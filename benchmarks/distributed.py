@@ -1,10 +1,12 @@
 """Bank-scaling benchmark worker: serving a mixed W2A2+W4A8 stream across
 a mesh of MVU banks (one 8-slot bank per jax device).
 
-Runs in its OWN process so it can force a multi-device host view before
-jax initializes (``--xla_force_host_platform_device_count``); the harness
-(:func:`benchmarks.run.bench_distributed`) spawns it and turns the JSON it
-prints into ``BENCH_distributed.json`` rows.
+:func:`run` measures on the devices the process has (four or more). The
+harness (:func:`benchmarks.run.bench_distributed`) calls it in-process
+where there are enough devices. On the CPU backend only, it starts this
+module as a worker process instead, which forces a multi-device host view
+(``--xla_force_host_platform_device_count``) before jax initializes and
+prints the result as JSON; a failure exits non-zero.
 
 Two scaling views are reported, deliberately separate:
 
@@ -23,14 +25,9 @@ Two scaling views are reported, deliberately separate:
 
 import json
 import os
-import sys
 import time
 
-N_DEVICES = int(os.environ.get("BENCH_BANK_DEVICES", "8"))
-os.environ.setdefault(
-    "XLA_FLAGS", f"--xla_force_host_platform_device_count={N_DEVICES}")
-
-import numpy as np  # noqa: E402
+import numpy as np
 
 
 def build_registry():
@@ -167,14 +164,12 @@ def pipelined(reg, key, n_stages=2, batch=32, iters=10):
             "stages": [list(b) for b in pl.stage_bounds]}
 
 
-def main():
+def run():
+    """Every measurement of this module on the process's own devices."""
     import jax
     n_dev = len(jax.devices())
     if n_dev < 4:
-        # exit 0: the error JSON on stdout IS the report — the harness
-        # parses it into a bench_distributed_error row
-        print(json.dumps({"error": f"only {n_dev} devices"}))
-        return 0
+        raise RuntimeError(f"bank scaling needs 4 devices, found {n_dev}")
     reg, k_lo, k_hi = build_registry()
     keys = (k_lo, k_hi)
     wall1 = serve_wall(reg, keys, 1)
@@ -182,16 +177,18 @@ def main():
     virt = virtual_scaling(reg, keys)
     shard = sharded_batch(reg, k_lo)
     pipe = pipelined(reg, k_lo)
-    print(json.dumps({
+    return {
         "n_devices": n_dev,
         "cpu_count": os.cpu_count(),
         "wall": {"1": wall1, "4": wall4},
         "virtual": {str(k): v for k, v in virt.items()},
         "sharded": shard,
         "pipelined": pipe,
-    }))
-    return 0
+    }
 
 
 if __name__ == "__main__":
-    sys.exit(main())
+    n = int(os.environ.get("BENCH_BANK_DEVICES", "8"))
+    os.environ.setdefault(
+        "XLA_FLAGS", f"--xla_force_host_platform_device_count={n}")
+    print(json.dumps(run()))

@@ -947,41 +947,35 @@ def bench_distributed():
     """Mesh-of-MVU-banks scaling: the mixed W2A2+W4A8 serving stream at 1
     vs 4 banks (one 8-slot bank per device).
 
-    Runs :mod:`benchmarks.distributed` in a subprocess so the worker can
-    force ``--xla_force_host_platform_device_count=8`` before jax
-    initializes. Scaling is reported in two domains: **virtual** (the
-    barrel-controller cycle clock the paper tables model — the >=2x CI
-    gate) and **wall** (this host; fake devices share the physical cores,
-    so wall scaling is informational).
+    Runs :func:`benchmarks.distributed.run` in this process on its own
+    devices. On the CPU backend with fewer than four devices it runs that
+    module as a worker process instead, which forces
+    ``--xla_force_host_platform_device_count=8`` before jax initializes;
+    that worker is never used where a chip may be held. A failure in
+    either raises, so the run exits non-zero. Scaling is reported in two
+    domains: **virtual** (the barrel-controller cycle clock the paper
+    tables model — the >=2x CI gate) and **wall** (this host; fake
+    devices share the physical cores, so wall scaling is informational).
     """
-    import json as _json
-    import subprocess
-    import sys
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)      # worker sets its own device count
-    env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
-    try:
+    import jax
+    from benchmarks import distributed
+    if len(jax.devices()) >= 4:
+        r = distributed.run()
+    elif os.environ.get("JAX_PLATFORMS") == "cpu":
+        import json as _json
+        import subprocess
+        import sys
+        env = dict(os.environ)
+        env.pop("XLA_FLAGS", None)      # worker sets its own device count
+        env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
         out = subprocess.run(
             [sys.executable, "-m", "benchmarks.distributed"],
-            capture_output=True, text=True, env=env, timeout=1200)
-    except subprocess.TimeoutExpired:
-        _emit("bench_distributed_error", 0, "worker timed out (1200s)",
-              group="distributed")
-        return
-    if out.returncode != 0:
-        _emit("bench_distributed_error", 0,
-              f"worker failed: {out.stderr[-300:]}", group="distributed")
-        return
-    try:
+            capture_output=True, text=True, env=env, timeout=1200,
+            check=True)
         r = _json.loads(out.stdout.strip().splitlines()[-1])
-    except (IndexError, ValueError):
-        _emit("bench_distributed_error", 0,
-              f"unparseable worker output: {out.stdout[-200:]!r}",
-              group="distributed")
-        return
-    if "error" in r:
-        _emit("bench_distributed_error", 0, r["error"], group="distributed")
-        return
+    else:
+        raise RuntimeError(
+            f"bench_distributed needs 4 devices, found {len(jax.devices())}")
     w1, w4 = r["wall"]["1"], r["wall"]["4"]
     v1, v4 = r["virtual"]["1"], r["virtual"]["4"]
     vscale = v1["virtual_seconds"] / v4["virtual_seconds"]
@@ -1140,6 +1134,8 @@ GROUPS = {
 
 
 def main(argv=None) -> None:
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma-separated bench groups "

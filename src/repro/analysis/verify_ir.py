@@ -139,9 +139,11 @@ _PACKED_KINDS = ("conv_packed", "gemm_packed")
 
 def _tile_vmem(step, cost_node, calib_batch: int, budget: int,
                blame: str) -> None:
-    """Re-derive the step's VMEM working set with the cost model's own
-    accounting and check it against the budget the tuner enumerated with."""
+    """Re-check the step's tuned tile as the tuner enumerated it: against
+    Mosaic's (8, 128) tiling rule, then its VMEM working set (the cost
+    model's own accounting) against the budget."""
     from repro.core import bitops, cost_model
+    from repro.kernels import tuning
 
     spec = step.attrs.get("spec")
     tile = step.attrs.get("tile")
@@ -154,6 +156,18 @@ def _tile_vmem(step, cost_node, calib_batch: int, budget: int,
     nd_w = bitops.num_digits(spec.w_bits, spec.radix_bits, spec.w_signed)
     out_bits = (step.attrs.get("requant_bits")
                 if step.attrs.get("out") == "packed" else None)
+    if step.kind == "conv_packed":
+        legal = tuning.conv_tile_ok(tile["block_co"])
+    else:
+        legal = tuning.matmul_tile_ok(
+            calib_batch, step.attrs["k"], cost_node.n, tile["block_m"],
+            tile["block_n"], tile["block_k"],
+            packed_out=out_bits is not None)
+    if not legal:
+        raise VerifyError(
+            "tile-rule",
+            f"step {step.name!r} ({step.kind}): tile {tile} breaks "
+            "Mosaic's (8, 128) tiling rule", blame=blame)
     if step.kind == "conv_packed":
         used = cost_model.conv_kernel_vmem_bytes(
             calib_batch, cost_node.h, cost_node.w, cost_node.c_in,
@@ -193,8 +207,9 @@ def verify_program(program, *, site: str = "post_lowering") -> None:
       program output is host-readable float;
     * ``precision-range`` / ``precision-spec`` — ``per_layer_bits`` are in
       [1, 8] and agree with each packed step's planned ``SerialSpec``;
-    * ``tile-vmem`` — each packed step's tuned tile fits the VMEM budget
-      (re-derived via :mod:`repro.core.cost_model`).
+    * ``tile-rule`` / ``tile-vmem`` — each packed step's tuned tile meets
+      Mosaic's (8, 128) tiling rule (:mod:`repro.kernels.tuning`) and fits
+      the VMEM budget (re-derived via :mod:`repro.core.cost_model`).
     """
     from repro.compiler.executor import _APPLY
     from repro.core import cost_model

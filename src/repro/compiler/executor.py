@@ -353,9 +353,22 @@ class BucketedRunner:
                              bank=bank))
         return self.compiles - before
 
+    def replica_devices(self) -> List[int]:
+        """Ids of the devices holding this runner's parameter replicas."""
+        if self._banks is not None:
+            trees = self._bank_params
+        elif self._sharded is not None:
+            trees = [self._sharded.params]
+        else:
+            trees = [self.program.params]
+        return sorted({d.id for t in trees for a in jax.tree.leaves(t)
+                       if hasattr(a, "devices") for d in a.devices()})
+
     def stats(self) -> Dict:
+        devices = self.replica_devices()
         with self._lock:
             return {"compiles": self.compiles, "hits": self.hits,
+                    "replica_devices": devices,
                     "buckets": sorted({b for _, b in self._seen}),
                     "bucket_set": bucket_sizes(self.max_batch,
                                                self._multiple),

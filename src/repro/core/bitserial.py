@@ -142,36 +142,28 @@ def serial_matmul(x: jax.Array, w: jax.Array, spec: SerialSpec) -> jax.Array:
 
 def digits_from_planes(planes: jax.Array, bits: int, radix_bits: int,
                        signed: bool) -> jax.Array:
-    """Assemble int8 digit planes DIRECTLY from {0,1} bit planes, entirely
-    in int8 — no int32 value materialization (this is what the Pallas
-    kernel does per VMEM tile; doing it here keeps the XLA serve path's
-    HBM traffic honest). ``planes``: (bits, ...) int8.
+    """Assemble int8 digit planes directly from {0,1} bit planes
+    (``planes``: (bits, ...), any integer dtype). The Pallas kernels run
+    this per VMEM tile, so it sticks to what the TPU vector unit supports:
+    shifts and adds in int32, with one cast to int8 per digit for the MXU.
 
     Signed top digit: the MSB plane enters with negative weight
-    −2^{bits−1−lo} (two's complement arithmetic shift), which fits int8
-    for radix_bits ≤ 8.
+    −2^{bits−1−lo} (two's complement), so every digit fits int8 for
+    radix_bits ≤ 8.
     """
     s = radix_bits
     n = bitops.num_digits(bits, s, signed)
     out = []
     for j in range(n):
         lo = j * s
-        hi = min(lo + s, bits)
-        d = planes[lo].astype(jnp.int8)
-        for t in range(lo + 1, hi):
-            p = planes[t].astype(jnp.int8)
-            shift = t - lo
-            if signed and j == n - 1 and t == bits - 1:
-                if shift == 7:
-                    # -128*p via two's-complement wrap of (p << 7)
-                    d = d + jnp.left_shift(p, 7)
-                else:
-                    d = d - p * jnp.int8(1 << shift)
+        d = None
+        for t in range(lo, min(lo + s, bits)):
+            term = jnp.left_shift(planes[t].astype(jnp.int32), t - lo)
+            if signed and t == bits - 1:
+                d = -term if d is None else d - term
             else:
-                d = d + p * jnp.int8(1 << shift)
-        if signed and j == n - 1 and hi - 1 == lo and lo == bits - 1:
-            d = -d  # single-bit top digit IS the MSB
-        out.append(d)
+                d = term if d is None else d + term
+        out.append(d.astype(jnp.int8))
     return jnp.stack(out)
 
 
@@ -246,8 +238,8 @@ def serial_matmul_packed_acts(
 
     Activation HBM bytes scale with ``a_bits`` just like weight bytes scale
     with ``w_bits`` — this is the XLA oracle of the v2 Pallas kernel, and
-    digit planes are assembled int8-only on BOTH sides via
-    :func:`digits_from_planes` (no int32 value materialization).
+    digit planes are assembled on BOTH sides by :func:`digits_from_planes`
+    (int32 shifts and adds, int8 digits for the matmuls).
     """
     a_planes = bitops.unpack_bitplanes(x_packed, k, axis=-1)  # (ba, M, K)
     w_planes = bitops.unpack_bitplanes(w_packed, k, axis=1)   # (bw, K, N)
@@ -335,8 +327,8 @@ def serial_conv2d_packed_acts(
     AGU tile walks. The largest intermediate is one row's tap gather
     (N, Ho, Wo, FW*Ci) — bounded at FW x one activation map, never the
     FH*FW x im2col patch tensor (and the Pallas kernel materializes
-    nothing at all). Digit planes are assembled int8-only on both sides
-    via :func:`digits_from_planes`.
+    nothing at all). Digit planes are assembled on both sides by
+    :func:`digits_from_planes` (int32 shifts and adds, int8 digits).
     """
     ba, n, h, wdt, _ = x_packed.shape
     bw, fh, fw, _, co = w_packed.shape

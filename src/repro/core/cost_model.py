@@ -150,14 +150,16 @@ def distributed_fps(layers: Sequence, a_bits: int, w_bits: int,
 
 @dataclasses.dataclass(frozen=True)
 class TPUConfig:
-    """Roofline constants for the Pallas kernel tile autotuner.
+    """Constants for the Pallas kernel tile autotuner.
 
-    Absolute numbers only set the ratio between the HBM and compute terms;
-    the tuner ranks *relative* tile costs, so default v4-class figures are
-    fine for CPU/interpret runs too.
+    ``vmem_bytes`` is the scoped-VMEM limit the kernels compile under: the
+    kernels pass it to Mosaic as ``vmem_limit_bytes``, and 16 MiB is also
+    the compiler's default scoped limit on TPU v5e. The other figures only
+    set the ratio between the HBM, MXU and VPU terms; the tuner ranks
+    *relative* tile costs.
     """
 
-    vmem_bytes: int = 16 * 2 ** 20        # per-core VMEM
+    vmem_bytes: int = 16 * 2 ** 20        # scoped VMEM limit per kernel
     vmem_budget_frac: float = 0.75        # leave headroom for the compiler
     hbm_bw: float = 8.0e11                # bytes/s
     int8_macs: float = 2.6e14             # MXU int8 MAC/s
@@ -174,6 +176,26 @@ def vmem_budget_bytes(tpu: "TPUConfig" = None) -> int:
     return int(tpu.vmem_bytes * tpu.vmem_budget_frac)
 
 
+def _tiled(shape, itemsize: int) -> int:
+    """Bytes a VMEM buffer takes in Mosaic's native tiling: the last dim
+    padded to 128 lanes, the second-last to 8 sublanes of 32-bit words
+    (16 rows for 16-bit, 32 for 8-bit types)."""
+    *lead, r, c = (1,) * max(0, 2 - len(shape)) + tuple(shape)
+    sub = 8 * (4 // itemsize)
+    return (math.prod(lead) * (-(-r // sub) * sub) * (-(-c // 128) * 128)
+            * itemsize)
+
+
+def _packer_bytes(rows: int, n: int, bits: int) -> int:
+    """Live VMEM of the in-kernel packer (``_pack_codes``) for a
+    (rows, n) code block: the {0,1} bf16 planes, the f32 word halves, and
+    one chunk's two (<=1024, <=128) bf16 power-of-two matrices with the
+    int32 temporaries that build them."""
+    mat = (min(n, 1024), min(n // 32, 128))
+    return (bits * _tiled((rows, n), 2) + 2 * bits * _tiled((rows, n // 32), 4)
+            + 2 * _tiled(mat, 2) + 3 * _tiled(mat, 4))
+
+
 def _grid_shape(m, k, n, bm, bn, bk):
     return (-(-n // bn), -(-m // bm), -(-k // bk))  # (n_j, n_i, n_k)
 
@@ -182,22 +204,27 @@ def kernel_vmem_bytes(m: int, k: int, n: int, *, a_bits: int, w_bits: int,
                       nd_a: int, nd_w: int, bm: int, bn: int, bk: int,
                       cache_weights: bool, cache_acts: bool,
                       out_bits: Optional[int] = None) -> int:
-    """VMEM working set of one v2 kernel invocation (bytes).
+    """VMEM working set of one v2 kernel invocation (bytes, tile-padded).
 
     BlockSpec-pipelined buffers are double-buffered (x2); scratch buffers
     (accumulator + cached digit planes) are single instances that persist
-    across the whole grid.
+    across the whole grid; the int8 digits of one step and the int32
+    plane being unpacked are live values the compiler also places in VMEM,
+    as are the packer's operands when the output is packed.
     """
     n_j, n_i, n_k = _grid_shape(m, k, n, bm, bn, bk)
-    x_tile = a_bits * bm * (bk // 32) * 4        # packed act tile, uint32
-    w_tile = w_bits * (bk // 32) * bn * 4        # packed weight tile
-    out_tile = (out_bits * bm * (bn // 32) * 4 if out_bits
-                else bm * bn * 4)
-    pipelined = 2 * (x_tile + w_tile + out_tile + 2 * bn * 4 + 4)
-    acc = bm * bn * 4
-    w_scr = n_k * nd_w * bk * bn if cache_weights else 0
-    a_scr = n_i * n_k * nd_a * bm * bk if cache_acts else 0
-    return pipelined + acc + w_scr + a_scr
+    x_tile = _tiled((a_bits, bm, bk // 32), 4)    # packed act tile, uint32
+    w_tile = _tiled((w_bits, bk // 32, bn), 4)    # packed weight tile
+    out_tile = (_tiled((out_bits, bm, bn // 32), 4) if out_bits
+                else _tiled((bm, bn), 4))
+    pipelined = 2 * (x_tile + w_tile + out_tile + 2 * _tiled((1, bn), 4))
+    acc = _tiled((bm, bn), 4)
+    live = (nd_a * _tiled((bm, bk), 1) + 3 * _tiled((bm, bk), 4)
+            + nd_w * _tiled((bk, bn), 1) + 3 * _tiled((bk, bn), 4)
+            + (_packer_bytes(bm, bn, out_bits) if out_bits else 0))
+    w_scr = _tiled((n_k * nd_w, bk, bn), 1) if cache_weights else 0
+    a_scr = _tiled((n_i * n_k * nd_a, bm, bk), 1) if cache_acts else 0
+    return pipelined + acc + live + w_scr + a_scr
 
 
 def kernel_cost(m: int, k: int, n: int, *, a_bits: int, w_bits: int,
@@ -255,25 +282,32 @@ def conv_kernel_vmem_bytes(n: int, h: int, w: int, ci: int, co: int, *,
                            bnb: int, bco: int, cache_weights: bool,
                            cache_acts: bool,
                            out_bits: Optional[int] = None) -> int:
-    """VMEM working set of one implicit-GEMM conv invocation (bytes).
+    """VMEM working set of one implicit-GEMM conv invocation (bytes,
+    tile-padded).
 
     Same accounting as :func:`kernel_vmem_bytes`: BlockSpec-pipelined
     buffers double-buffered (x2); scratches (accumulator + digit-plane
-    caches + the in-register assembled row/tap planes) single instances.
+    caches) single instances; the assembled tap planes of one step and
+    the packer's operands live.
     """
     ho, wo, hp, wp, ciw, n_nb, n_j = _conv_geom(
         n, h, w, ci, fh, fw, stride, padding, bnb, bco, co)
     ci_pad = ciw * 32
-    x_tile = a_bits * bnb * wp * ciw * 4          # one packed input row
-    w_tile = w_bits * fw * ciw * bco * 4          # one packed filter-row tap
-    out_tile = (out_bits * bnb * wo * (bco // 32) * 4 if out_bits
-                else bnb * wo * bco * 4)
-    pipelined = 2 * (x_tile + w_tile + out_tile + 2 * bco * 4 + 4)
-    acc = bnb * wo * bco * 4
-    # assembled digit planes live in registers/VMEM even when not cached
-    live = nd_a * bnb * wp * ci_pad + nd_w * fw * ci_pad * bco
-    w_scr = fh * nd_w * fw * ci_pad * bco if cache_weights else 0
-    a_scr = n_nb * hp * nd_a * bnb * wp * ci_pad if cache_acts else 0
+    rows = bnb * wo                               # GEMM rows per step
+    x_tile = _tiled((a_bits * bnb, wp, ciw), 4)   # one packed input row
+    w_tile = _tiled((w_bits * fw, ciw, bco), 4)   # one packed filter-row tap
+    out_tile = (_tiled((out_bits * bnb, wo, bco // 32), 4) if out_bits
+                else _tiled((bnb, wo, bco), 4))
+    pipelined = 2 * (x_tile + w_tile + out_tile + 2 * _tiled((1, bco), 4))
+    acc = _tiled((rows, bco), 4)
+    live = (fw * nd_a * _tiled((rows, ci_pad), 1)
+            + 3 * _tiled((rows, ci_pad), 4)
+            + fw * nd_w * _tiled((ci_pad, bco), 1)
+            + 3 * _tiled((ci_pad, bco), 4)
+            + (_packer_bytes(rows, bco, out_bits) if out_bits else 0))
+    w_scr = _tiled((fh * nd_w * fw, ci_pad, bco), 1) if cache_weights else 0
+    a_scr = (_tiled((n_nb * ho * fh * fw * nd_a, rows, ci_pad), 1)
+             if cache_acts else 0)
     return pipelined + acc + live + w_scr + a_scr
 
 
@@ -288,8 +322,8 @@ def conv_kernel_cost(n: int, h: int, w: int, ci: int, co: int, *,
 
     The hoisting shows up exactly as in :func:`kernel_cost`: cached
     weight-tap planes are assembled once per (Co-block, f_h) instead of
-    once per grid step; cached activation rows once per input row instead
-    of once per (Co-block, output-row, f_h) visit.
+    once per grid step; cached activation taps once per (output-row, f_h)
+    instead of once per Co-block visit.
     """
     ho, wo, hp, wp, ciw, n_nb, n_j = _conv_geom(
         n, h, w, ci, fh, fw, stride, padding, bnb, bco, co)
@@ -308,7 +342,7 @@ def conv_kernel_cost(n: int, h: int, w: int, ci: int, co: int, *,
 
     # digit-plane assembly (unpack shifts + int8 scale-adds), VPU-bound
     tap_work = (w_bits + nd_w) * fw * ci_pad * bco
-    row_work = (a_bits + nd_a) * bnb * wp * ci_pad
+    row_work = (a_bits + nd_a) * fw * bnb * wo * ci_pad
     w_asm = tap_work * n_j * fh * (1 if cache_weights else n_m)
     a_asm = row_work * n_m * fh * (1 if cache_acts else n_j)
     epilogue = n_m * bnb * wo * n_j * bco * (3 + (out_bits or 0))

@@ -35,10 +35,7 @@ def compressed_allreduce_mean(g: jax.Array, axis_name: str) -> jax.Array:
     chunk is requantized to int8 and all-gathered. Must run inside
     ``shard_map`` (manual axes).
     """
-    # jax.lax.axis_size only exists on newer jax; psum(1) is the portable
-    # spelling of "size of the named axis" inside manual collectives
-    n = (jax.lax.axis_size(axis_name) if hasattr(jax.lax, "axis_size")
-         else int(jax.lax.psum(1, axis_name)))
+    n = jax.lax.axis_size(axis_name)
     flat = g.reshape(-1)
     pad = (-flat.size) % n
     flat = jnp.pad(flat, (0, pad))

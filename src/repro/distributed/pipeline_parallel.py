@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.compat import shard_map
-
 __all__ = ["gpipe", "stage_stack"]
 
 
@@ -94,8 +92,8 @@ def gpipe(stage_fn: Callable, stage_params, x, *, mesh: Mesh,
             stage_axis)
         return outs
 
-    ym = shard_map(run, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs,
-                   axis_names=frozenset({stage_axis}),
-                   check=False)(stage_params, xm)
+    ym = jax.shard_map(run, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs,
+                       axis_names=frozenset({stage_axis}),
+                       check_vma=False)(stage_params, xm)
     return ym.reshape((b,) + x.shape[1:])

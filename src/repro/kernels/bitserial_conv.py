@@ -17,14 +17,16 @@ VMEM:
   one output row × one filter-row tap. The k-step (``f_h``) selects the
   input row ``ih = oh·stride + f_h`` directly in the BlockSpec index map
   (the AGU's row walk); the ``f_w`` taps are walked *inside* the kernel by
-  static strided slices of the row held in VMEM (the AGU's column walk) —
-  patch generation is free address arithmetic, exactly like the hardware.
-* Digit planes are assembled int8-only (``digits_from_planes``) and cached
-  in VMEM scratch mirroring the v2 matmul kernel: weight-tap digits once
-  per (Co-block, f_h) — reused by every output row — and activation-row
-  digits once per input row — reused by every Co-block. ``radix_bits=1``
-  reproduces Algorithm 1 literally; ``radix_bits=7/8`` is the MXU-native
-  digit-serial variant (radix chosen by ``plan_spec``).
+  static strided loads of the packed row held in VMEM (the AGU's column
+  walk) — patch generation is free address arithmetic, exactly like the
+  hardware.
+* Digit planes are assembled by ``digits_from_planes`` (int32 shifts and
+  adds, int8 only for the MXU operands) and cached in VMEM scratch
+  mirroring the v2 matmul kernel: weight-tap digits once per (Co-block,
+  f_h) — reused by every output row — and the activation tap digits of
+  each (image block, output row, f_h) once — reused by every Co-block.
+  ``radix_bits=1`` reproduces Algorithm 1 literally; ``radix_bits=7/8`` is
+  the MXU-native digit-serial variant (radix chosen by ``plan_spec``).
 * The epilogue fuses the MVU post-pipeline on the last tap: per-output
   channel scaler + bias, optional ReLU comparator, optional
   quantizer/serializer — with ``emit_packed=True`` it writes
@@ -49,29 +51,43 @@ import jax.experimental.pallas.tpu as pltpu
 from repro.core import bitops
 from repro.core.bitserial import SerialSpec, conv_out_hw, digits_from_planes
 from repro.core.quant import QuantSpec, qrange
-from repro.kernels.bitserial_matmul import (_CompilerParams, _digit_matmul_acc,
-                                            _pack_codes, _unpack_plane_words)
+from repro.kernels.bitserial_matmul import (_Planes, _channel_rows,
+                                            _compiler_params,
+                                            _digit_matmul_acc, _pack_codes,
+                                            _smem_scalar)
 
 __all__ = ["bitserial_conv2d_v2_pallas"]
 
 
-def _assemble_row_digits(x_words, ci_pad: int, spec: SerialSpec):
-    """(ba, bnb, 1, Wp, G) uint32 -> (nd_a, bnb, Wp, ci_pad) int8 digits."""
-    planes = _unpack_plane_words(x_words[:, :, 0], ci_pad, axis_word=2)
-    return digits_from_planes(planes, spec.a_bits, spec.radix_bits,
-                              spec.a_signed)
+def _assemble_tap_digits(w_ref, spec: SerialSpec, fw: int):
+    """Weight block (1, bw, 1, FW, G, bn) uint32 -> (nd_w, FW, 32G, bn)
+    int8 digits."""
+    words = w_ref[0, :, 0]
+    return jnp.stack(
+        [digits_from_planes(_Planes(words[:, i_fw], 0), spec.w_bits,
+                            spec.radix_bits, spec.w_signed)
+         for i_fw in range(fw)], axis=1)
 
 
-def _assemble_tap_digits(w_words, ci_pad: int, spec: SerialSpec):
-    """(bw, 1, FW, G, bn) uint32 -> (nd_w, FW, ci_pad, bn) int8 digits."""
-    planes = _unpack_plane_words(w_words[:, 0], ci_pad, axis_word=1)
-    return digits_from_planes(planes, spec.w_bits, spec.radix_bits,
-                              spec.w_signed)
+def _assemble_row_digits(x_ref, spec: SerialSpec, *, nb: int, fw: int,
+                         wo: int, stride: int):
+    """The FW tap windows of one input row block -> (FW, nd_a, nb*wo,
+    32G) int8 digits. Tap ``f_w`` takes columns ``f_w + stride*o``: a
+    strided load of the packed words (the AGU's column walk), then the
+    images of the block stacked along the GEMM's M axis."""
+    taps = []
+    for i_fw in range(fw):
+        cols = pl.ds(i_fw, wo, stride)
+        words = jnp.concatenate(
+            [x_ref[:, b, 0, cols, :] for b in range(nb)], axis=1)
+        taps.append(digits_from_planes(_Planes(words, 1), spec.a_bits,
+                                       spec.radix_bits, spec.a_signed))
+    return jnp.stack(taps)
 
 
 def _kernel(x_ref, w_ref, scale_ref, bias_ref, rs_ref, out_ref, acc_ref,
             *scratch, spec: SerialSpec, fh: int, fw: int, stride: int,
-            ho: int, wo: int, hp: int, ci_pad: int, relu: bool, out_dtype,
+            wo: int, nb: int, relu: bool, out_dtype,
             requant: Optional[QuantSpec], emit_packed: bool,
             cache_weights: bool, cache_acts: bool):
     j = pl.program_id(0)    # Co-block (outermost)
@@ -92,62 +108,51 @@ def _kernel(x_ref, w_ref, scale_ref, bias_ref, rs_ref, out_ref, acc_ref,
         @pl.when(m == 0)
         def _fill_w():
             w_scr[pl.ds(kk, 1)] = _assemble_tap_digits(
-                w_ref[...], ci_pad, spec)[None]
+                w_ref, spec, fw)[None]
         wd = w_scr[pl.ds(kk, 1)][0]
     else:
-        wd = _assemble_tap_digits(w_ref[...], ci_pad, spec)
+        wd = _assemble_tap_digits(w_ref, spec, fw)
 
-    # --- activation-row digit planes: row ih = oh*stride + f_h of image
-    # block nb is assembled while j == 0 and reused by every later Co-block
-    # (rows shared between overlapping taps are re-assembled at j == 0 —
-    # idempotent writes, still once per row for all j > 0) ---------------
+    # --- activation tap digit planes of (image block, output row, f_h):
+    # assembled while j == 0 and reused by every later Co-block ----------
+    row = functools.partial(_assemble_row_digits, x_ref, spec, nb=nb,
+                            fw=fw, wo=wo, stride=stride)
     if cache_acts:
-        slot = (m // ho) * hp + (m % ho) * stride + kk
+        slot = m * fh + kk
+
         @pl.when(j == 0)
         def _fill_a():
-            a_scr[pl.ds(slot, 1)] = _assemble_row_digits(
-                x_ref[...], ci_pad, spec)[None]
+            a_scr[pl.ds(slot, 1)] = row()[None]
         xd = a_scr[pl.ds(slot, 1)][0]
     else:
-        xd = _assemble_row_digits(x_ref[...], ci_pad, spec)
+        xd = row()
 
-    bnb = x_ref.shape[1]
-    mrows = bnb * wo
-
-    # --- the f_w taps: AGU column walk, in-register strided selection ---
+    # --- the f_w taps: one digit-plane GEMM each ------------------------
     tile = None
     for i_fw in range(fw):
-        xs = jax.lax.slice(
-            xd, (0, 0, i_fw, 0),
-            (xd.shape[0], bnb, i_fw + wo * stride, ci_pad))
-        if stride > 1:
-            xs = xs.reshape(xd.shape[0], bnb, wo, stride, ci_pad)[:, :, :, 0]
-        xs = xs.reshape(xd.shape[0], mrows, ci_pad)
-        p = _digit_matmul_acc(xs, wd[:, i_fw], spec.radix_bits)
+        p = _digit_matmul_acc(xd[i_fw], wd[:, i_fw], spec.radix_bits)
         tile = p if tile is None else tile + p
     acc_ref[...] += tile
 
     @pl.when(kk == fh - 1)
     def _epilogue():
-        acc = acc_ref[...].astype(jnp.float32)
-        out = acc * scale_ref[...].astype(jnp.float32)[None, :]
-        out = out + bias_ref[...].astype(jnp.float32)[None, :]
+        out = acc_ref[...].astype(jnp.float32) * scale_ref[0] + bias_ref[0]
         if relu:
             out = jnp.maximum(out, 0.0)
-        bn = out.shape[-1]
         if requant is None:
-            out_ref[...] = out.astype(out_dtype).reshape(bnb, 1, wo, bn)
+            y = out.astype(out_dtype)
         else:
             qn, qp = qrange(requant.bits, requant.signed)
             codes = jnp.clip(jnp.round(out / rs_ref[0]), qn, qp).astype(
                 jnp.int32)
             if emit_packed:
-                out_ref[...] = _pack_codes(codes, requant.bits).reshape(
-                    requant.bits, bnb, 1, wo, bn // 32)
-            else:
-                out_ref[...] = codes.astype(
-                    jnp.int8 if requant.bits <= 8 else jnp.int32).reshape(
-                        bnb, 1, wo, bn)
+                words = _pack_codes(codes, requant.bits)
+                for b in range(nb):
+                    out_ref[:, 0, b, 0] = words[:, b * wo:(b + 1) * wo]
+                return
+            y = codes.astype(jnp.int8 if requant.bits <= 8 else jnp.int32)
+        for b in range(nb):
+            out_ref[0, b, 0] = y[b * wo:(b + 1) * wo]
 
 
 def bitserial_conv2d_v2_pallas(
@@ -211,23 +216,23 @@ def bitserial_conv2d_v2_pallas(
     nb = max(1, min(block_nb, n))
     np_img = -(-n // nb) * nb
     co_p = -(-co // block_co) * block_co
+    n_nb = np_img // nb
+    n_j = co_p // block_co
     x_packed = jnp.pad(
         x_packed,
         ((0, 0), (0, np_img - n), (padding, hp - h - padding),
          (padding, wp - w_in - padding), (0, 0)))
+    # Co-blocks ride a leading axis of the weights, scaler/bias and output,
+    # so that every block's last two dims are the array's own and any
+    # block_co % 32 meets Mosaic's (8, 128) tiling rule (with one Co-block
+    # these are free reshapes)
     w_packed = jnp.pad(w_packed, ((0, 0), (0, 0), (0, 0), (0, 0),
                                   (0, co_p - co)))
-    scale = jnp.pad(jnp.broadcast_to(jnp.asarray(scale, jnp.float32), (co,)),
-                    (0, co_p - co))
-    bias = jnp.zeros((co,), jnp.float32) if bias is None else jnp.asarray(
-        bias, jnp.float32)
-    bias = jnp.pad(bias, (0, co_p - co))
-    rs = jnp.broadcast_to(
-        jnp.asarray(1.0 if requant_scale is None else requant_scale,
-                    jnp.float32), (1,))
-
-    n_nb = np_img // nb
-    n_j = co_p // block_co
+    w_packed = jnp.moveaxis(
+        w_packed.reshape(bw, fh, fw, ciw, n_j, block_co), 4, 0)
+    scale, bias = (v.reshape(n_j, 1, block_co)
+                   for v in _channel_rows(scale, bias, co, co_p))
+    rs = _smem_scalar(requant_scale)
     grid = (n_j, n_nb * ho, fh)
 
     nd_a = bitops.num_digits(spec.a_bits, spec.radix_bits, spec.a_signed)
@@ -238,9 +243,8 @@ def bitserial_conv2d_v2_pallas(
     # and budget as the tuner (a tuner-approved config therefore always
     # passes unmodified — pass the tuner's ``tpu`` when using a non-default
     # part): drop caches, activations first, until the working set fits.
-    from repro.core.cost_model import TPUConfig, conv_kernel_vmem_bytes
-    _tpu = tpu if tpu is not None else TPUConfig()
-    budget = int(_tpu.vmem_bytes * _tpu.vmem_budget_frac)
+    from repro.core.cost_model import conv_kernel_vmem_bytes, vmem_budget_bytes
+    budget = vmem_budget_bytes(tpu)
 
     def _vmem(cw, ca):
         return conv_kernel_vmem_bytes(
@@ -257,25 +261,26 @@ def bitserial_conv2d_v2_pallas(
     if cache_weights:
         scratch.append(pltpu.VMEM((fh, nd_w, fw, ci_pad, block_co), jnp.int8))
     if cache_acts:
-        scratch.append(pltpu.VMEM((n_nb * hp, nd_a, nb, wp, ci_pad),
-                                  jnp.int8))
+        scratch.append(pltpu.VMEM((n_nb * ho * fh, fw, nd_a, nb * wo,
+                                   ci_pad), jnp.int8))
 
     if emit_packed:
         out_shape = jax.ShapeDtypeStruct(
-            (requant.bits, np_img, ho, wo, co_p // 32), jnp.uint32)
+            (requant.bits, n_j, np_img, ho, wo, block_co // 32), jnp.uint32)
         out_spec = pl.BlockSpec(
-            (requant.bits, nb, 1, wo, block_co // 32),
-            lambda j, m, kk: (0, m // ho, m % ho, 0, j))
+            (requant.bits, 1, nb, 1, wo, block_co // 32),
+            lambda j, m, kk: (0, j, m // ho, m % ho, 0, 0))
     else:
         out_dt = (jnp.int8 if requant is not None and requant.bits <= 8
                   else (jnp.int32 if requant is not None else out_dtype))
-        out_shape = jax.ShapeDtypeStruct((np_img, ho, wo, co_p), out_dt)
-        out_spec = pl.BlockSpec((nb, 1, wo, block_co),
-                                lambda j, m, kk: (m // ho, m % ho, 0, j))
+        out_shape = jax.ShapeDtypeStruct((n_j, np_img, ho, wo, block_co),
+                                         out_dt)
+        out_spec = pl.BlockSpec((1, nb, 1, wo, block_co),
+                                lambda j, m, kk: (j, m // ho, m % ho, 0, 0))
 
     kernel = functools.partial(
-        _kernel, spec=spec, fh=fh, fw=fw, stride=stride, ho=ho, wo=wo, hp=hp,
-        ci_pad=ci_pad, relu=relu, out_dtype=out_dtype, requant=requant,
+        _kernel, spec=spec, fh=fh, fw=fw, stride=stride, wo=wo, nb=nb,
+        relu=relu, out_dtype=out_dtype, requant=requant,
         emit_packed=emit_packed, cache_weights=cache_weights,
         cache_acts=cache_acts)
 
@@ -288,21 +293,24 @@ def bitserial_conv2d_v2_pallas(
             pl.BlockSpec((ba, nb, 1, wp, ciw),
                          lambda j, m, kk: (0, m // ho,
                                            (m % ho) * stride + kk, 0, 0)),
-            pl.BlockSpec((bw, 1, fw, ciw, block_co),
-                         lambda j, m, kk: (0, kk, 0, 0, j)),
-            pl.BlockSpec((block_co,), lambda j, m, kk: (j,)),
-            pl.BlockSpec((block_co,), lambda j, m, kk: (j,)),
-            pl.BlockSpec((1,), lambda j, m, kk: (0,)),
+            pl.BlockSpec((1, bw, 1, fw, ciw, block_co),
+                         lambda j, m, kk: (j, 0, kk, 0, 0, 0)),
+            pl.BlockSpec((1, 1, block_co), lambda j, m, kk: (j, 0, 0)),
+            pl.BlockSpec((1, 1, block_co), lambda j, m, kk: (j, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=out_spec,
         out_shape=out_shape,
         scratch_shapes=scratch,
         # scratch reuse spans grid steps along every dimension, so all three
         # must stay sequential on one core ("arbitrary", not "parallel")
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        compiler_params=_compiler_params("arbitrary", "arbitrary",
+                                         "arbitrary"),
         interpret=interpret,
     )(x_packed, w_packed, scale, bias, rs)
+    # Co-blocks back onto the channel axis
+    out = jnp.moveaxis(out, -5, -2)
+    out = out.reshape(out.shape[:-2] + (-1,))
     if emit_packed:
         return out[:, :n, :, :, : -(-co // 32)]
     return out[:n, :, :, :co]

@@ -42,23 +42,117 @@ from repro.core.quant import QuantSpec, qrange
 
 __all__ = ["bitserial_matmul_pallas", "bitserial_matmul_v2_pallas"]
 
-# jax renamed TPUCompilerParams -> CompilerParams across versions; take
-# whichever this interpreter ships.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
+def _compiler_params(*semantics: str):
+    """Mosaic compile options shared by the bit-serial kernels: the grid's
+    dimension semantics, and the scoped-VMEM limit set to the same
+    ``TPUConfig.vmem_bytes`` the tile tuner budgets against."""
+    from repro.core.cost_model import TPUConfig
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=TPUConfig().vmem_bytes)
 
 
-def _unpack_planes(words, block_k: int):
-    """(bw, G, bn) uint32 -> list of (block_k, bn) int8 {0,1} planes."""
-    bw, g, bn = words.shape
-    shifts = jnp.arange(32, dtype=jnp.uint32).reshape(1, 32, 1)
-    planes = []
-    for b in range(bw):
-        bits = jnp.bitwise_and(
-            jnp.right_shift(words[b][:, None, :], shifts), jnp.uint32(1)
-        )
-        planes.append(bits.reshape(g * 32, bn)[:block_k].astype(jnp.int8))
-    return planes
+def _unpack_words(words, axis: int):
+    """uint32 packed words of one plane -> {0,1} int32 bit plane, the word
+    axis ``axis`` expanded 32-fold in place (element ``32g+t`` = bit ``t``
+    of word ``g``, the :func:`repro.core.bitops.pack_bitplanes` layout).
+
+    Each word is repeated over its 32 lanes and shifted by the lane's bit
+    index: int32 shifts and masks only, which Mosaic lowers along either
+    the lane or the sublane axis."""
+    axis %= words.ndim
+    rep = jnp.repeat(jax.lax.bitcast_convert_type(words, jnp.int32), 32,
+                     axis=axis)
+    shape = [1] * rep.ndim
+    shape[axis] = rep.shape[axis]
+    t = jax.lax.broadcasted_iota(jnp.int32, tuple(shape), axis)
+    return jnp.bitwise_and(jnp.right_shift(rep, jnp.bitwise_and(t, 31)), 1)
+
+
+class _Planes:
+    """The bit planes of packed words (``words[t]`` = plane ``t``),
+    unpacked along ``axis`` only when indexed: digit assembly reads each
+    plane once, in order, so one int32 plane is live at a time in VMEM."""
+
+    def __init__(self, words, axis: int):
+        self.words, self.axis = words, axis
+
+    def __getitem__(self, t):
+        return _unpack_words(self.words[t], self.axis)
+
+
+_PACK_LANES = 1024   # lanes gathered by one packing matmul
+_PACK_WORDS = 128    # words of one output lane tile
+
+
+def _pack_matrices(lanes: int, words: int, word0: int):
+    """The two (lanes, words) bf16 power-of-two matrices that gather
+    ``lanes`` code lanes into the ``words`` words of one output lane tile:
+    lane ``l`` feeds word ``word0 + l // 32`` with weight ``2^(l % 16)``,
+    the low 16 lanes of a word in the first matrix and the high 16 in the
+    second."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (lanes, words), 0)
+    word = jax.lax.broadcasted_iota(jnp.int32, (lanes, words), 1)
+    t = jnp.bitwise_and(lane, 31)
+    own = jnp.right_shift(lane, 5) + word0 == word
+    pow2 = jnp.left_shift(1, jnp.bitwise_and(t, 15))
+    return [jnp.where(own & ((t >= 16) == hi), pow2, 0).astype(
+        jnp.float32).astype(jnp.bfloat16) for hi in (False, True)]
+
+
+def _pack_codes(codes, bits: int):
+    """(R, N) int32 codes -> (bits, R, N/32) uint32 packed planes.
+
+    The in-kernel serializer, in the word layout of
+    :func:`repro.core.bitops.pack_bitplanes` (lane ``t`` of a 32-lane group
+    -> bit ``t`` of its word). Gathering 32 lanes into one word is a
+    matmul of each {0,1} plane with a block-diagonal matrix of powers of
+    two, done in two 16-bit halves so that every product and partial sum
+    is an integer below 2^16: exact with bf16 operands and f32
+    accumulation. The matmuls go one output lane tile (128 words) and
+    1024 lanes at a time, so the matrices stay at most (1024, 128)
+    whatever N is.
+    """
+    n = codes.shape[-1]
+    n_words = n // 32
+    u = jnp.bitwise_and(codes, (1 << bits) - 1)
+    bit_planes = [jnp.bitwise_and(jnp.right_shift(u, b), 1).astype(
+        jnp.float32).astype(jnp.bfloat16) for b in range(bits)]
+    tiles = [[] for _ in range(bits)]
+    for w0 in range(0, n_words, _PACK_WORDS):
+        words = min(_PACK_WORDS, n_words - w0)
+        halves = [None] * bits
+        for l0 in range(w0 * 32, (w0 + words) * 32, _PACK_LANES):
+            lanes = min(_PACK_LANES, (w0 + words) * 32 - l0)
+            mats = _pack_matrices(lanes, words, (l0 - w0 * 32) // 32)
+            for b, bit in enumerate(bit_planes):
+                part = [jnp.dot(bit[:, l0:l0 + lanes], m,
+                                preferred_element_type=jnp.float32)
+                        for m in mats]
+                halves[b] = (part if halves[b] is None else
+                             [h + p for h, p in zip(halves[b], part)])
+        for b in range(bits):
+            lo, hi = (h.astype(jnp.int32) for h in halves[b])
+            tiles[b].append(jax.lax.bitcast_convert_type(
+                jnp.bitwise_or(lo, jnp.left_shift(hi, 16)), jnp.uint32))
+    return jnp.stack([t[0] if len(t) == 1 else jnp.concatenate(t, axis=-1)
+                      for t in tiles])
+
+
+def _channel_rows(scale, bias, n: int, np_: int):
+    """Per-output-channel scaler and bias as (1, np_) f32 rows (zero-padded
+    past ``n``): 2-D, so that their (1, block) tiles lay out on the
+    vector unit the same way in XLA and in Mosaic."""
+    scale = jnp.broadcast_to(jnp.asarray(scale, jnp.float32), (n,))
+    bias = (jnp.zeros((n,), jnp.float32) if bias is None
+            else jnp.asarray(bias, jnp.float32))
+    return (jnp.pad(v, (0, np_ - n)).reshape(1, np_) for v in (scale, bias))
+
+
+def _smem_scalar(v) -> jax.Array:
+    """A kernel's scalar operand (the requant step) as a (1,) f32 array
+    for SMEM (1.0 when absent)."""
+    return jnp.broadcast_to(
+        jnp.asarray(1.0 if v is None else v, jnp.float32), (1,))
 
 
 def _weight_operands(planes, spec: SerialSpec):
@@ -66,29 +160,17 @@ def _weight_operands(planes, spec: SerialSpec):
 
     radix_bits == 1: the bit planes themselves (faithful Algorithm 1), with
     the signed-MSB plane carrying a negative unit coefficient.
-    radix_bits > 1 : reconstruct values, split into int8 digits.
+    radix_bits > 1 : int8 digits assembled from the planes.
+    ``planes[k]``: (bk, bn) {0,1} int32.
     Returns list of (plane:int8 (bk,bn), magnitude:int, negate:bool).
     """
     s = spec.radix_bits
     bw = spec.w_bits
     if s == 1:
-        out = []
-        for kbit, p in enumerate(planes):
-            neg = spec.w_signed and kbit == bw - 1
-            out.append((p, kbit, neg))
-        return out
-    coeffs = bitops.plane_coeffs(bw, spec.w_signed)
-    vals = planes[0].astype(jnp.int32) * int(coeffs[0])
-    for kbit in range(1, bw):
-        vals = vals + planes[kbit].astype(jnp.int32) * int(coeffs[kbit])
-    nd = bitops.num_digits(bw, s, spec.w_signed)
-    out = []
-    for j in range(nd):
-        d = jnp.right_shift(vals, j * s)
-        if j < nd - 1:
-            d = jnp.bitwise_and(d, (1 << s) - 1)
-        out.append((d.astype(jnp.int8), j * s, False))
-    return out
+        return [(planes[kbit].astype(jnp.int8), kbit,
+                 spec.w_signed and kbit == bw - 1) for kbit in range(bw)]
+    digits = digits_from_planes(planes, bw, s, spec.w_signed)
+    return [(digits[j], j * s, False) for j in range(digits.shape[0])]
 
 
 def _act_operands(x_tile, spec: SerialSpec):
@@ -121,7 +203,7 @@ def _act_operands(x_tile, spec: SerialSpec):
 
 
 def _kernel(x_ref, w_ref, scale_ref, bias_ref, out_ref, acc_ref, *,
-            spec: SerialSpec, block_k: int, relu: bool, out_dtype,
+            spec: SerialSpec, relu: bool, out_dtype,
             requant: Optional[QuantSpec], n_k: int):
     kk = pl.program_id(2)
 
@@ -129,8 +211,7 @@ def _kernel(x_ref, w_ref, scale_ref, bias_ref, out_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w_planes = _unpack_planes(w_ref[...], block_k)
-    w_ops = _weight_operands(w_planes, spec)
+    w_ops = _weight_operands(_Planes(w_ref, 0), spec)
     x_ops = _act_operands(x_ref[...], spec)
 
     # magnitude-major Horner over plane pairs (Algorithm 1): gather equal
@@ -158,8 +239,7 @@ def _kernel(x_ref, w_ref, scale_ref, bias_ref, out_ref, acc_ref, *,
     @pl.when(kk == n_k - 1)
     def _epilogue():
         acc = acc_ref[...].astype(jnp.float32)
-        out = acc * scale_ref[...].astype(jnp.float32)[None, :]
-        out = out + bias_ref[...].astype(jnp.float32)[None, :]
+        out = acc * scale_ref[...] + bias_ref[...]
         if relu:
             out = jnp.maximum(out, 0.0)
         if requant is not None:
@@ -208,18 +288,15 @@ def bitserial_matmul_pallas(
     x = jnp.pad(x.astype(jnp.int8 if spec.a_bits <= 8 else jnp.int32),
                 ((0, mp - m), (0, kp - k)))
     w_packed = jnp.pad(w_packed, ((0, 0), (0, kp // 32 - kwords), (0, np_ - n)))
-    scale = jnp.pad(jnp.broadcast_to(jnp.asarray(scale, jnp.float32), (n,)),
-                    (0, np_ - n))
-    bias = jnp.zeros((n,), jnp.float32) if bias is None else jnp.asarray(bias, jnp.float32)
-    bias = jnp.pad(bias, (0, np_ - n))
+    scale, bias = _channel_rows(scale, bias, n, np_)
 
     n_k = kp // block_k
     grid = (mp // block_m, np_ // block_n, n_k)
     out_dt = jnp.int8 if requant is not None and requant.bits <= 8 else out_dtype
 
     kernel = functools.partial(
-        _kernel, spec=spec, block_k=block_k, relu=relu, out_dtype=out_dt,
-        requant=requant, n_k=n_k)
+        _kernel, spec=spec, relu=relu, out_dtype=out_dt, requant=requant,
+        n_k=n_k)
 
     out = pl.pallas_call(
         kernel,
@@ -228,14 +305,14 @@ def bitserial_matmul_pallas(
             pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bw, block_k // 32, block_n),
                          lambda i, j, kk: (0, kk, j)),
-            pl.BlockSpec((block_n,), lambda i, j, kk: (j,)),
-            pl.BlockSpec((block_n,), lambda i, j, kk: (j,)),
+            pl.BlockSpec((1, block_n), lambda i, j, kk: (0, j)),
+            pl.BlockSpec((1, block_n), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dt),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_compiler_params("parallel", "parallel",
+                                         "arbitrary"),
         interpret=interpret,
     )(x, w_packed, scale, bias)
     return out[:m, :n]
@@ -245,33 +322,18 @@ def bitserial_matmul_pallas(
 # v2: packed activations, hoisted plane work, fused requant-pack epilogue
 # ===========================================================================
 
-def _unpack_plane_words(words, length: int, axis_word: int):
-    """Unpack uint32 words into {0,1} int8 bit planes along ``axis_word``.
-
-    ``words``: (bits, ..., G, ...) with the 32-lane word axis at
-    ``axis_word`` (relative to one plane, i.e. excluding the leading bits
-    axis). Returns (bits, ...) int8 with that axis expanded to ``length``.
-    """
-    shifts = jnp.arange(32, dtype=jnp.uint32)
-    x = jnp.moveaxis(words, axis_word + 1, -1)
-    bits = jnp.bitwise_and(
-        jnp.right_shift(x[..., None], shifts), jnp.uint32(1)).astype(jnp.int8)
-    bits = bits.reshape(bits.shape[:-2] + (x.shape[-1] * 32,))[..., :length]
-    return jnp.moveaxis(bits, -1, axis_word + 1)
+def _assemble_w_digits(w_words, spec: SerialSpec):
+    """(bw, G, bn) uint32 words (array or ref) -> (nd_w, 32G, bn) int8
+    digit planes."""
+    return digits_from_planes(_Planes(w_words, 0), spec.w_bits,
+                              spec.radix_bits, spec.w_signed)
 
 
-def _assemble_w_digits(w_words, block_k: int, spec: SerialSpec):
-    """(bw, G, bn) uint32 -> (nd_w, block_k, bn) int8 digit planes."""
-    planes = _unpack_plane_words(w_words, block_k, axis_word=0)
-    return digits_from_planes(planes, spec.w_bits, spec.radix_bits,
-                              spec.w_signed)
-
-
-def _assemble_a_digits(a_words, block_k: int, spec: SerialSpec):
-    """(ba, bm, G) uint32 -> (nd_a, bm, block_k) int8 digit planes."""
-    planes = _unpack_plane_words(a_words, block_k, axis_word=1)
-    return digits_from_planes(planes, spec.a_bits, spec.radix_bits,
-                              spec.a_signed)
+def _assemble_a_digits(a_words, spec: SerialSpec):
+    """(ba, bm, G) uint32 words (array or ref) -> (nd_a, bm, 32G) int8
+    digit planes."""
+    return digits_from_planes(_Planes(a_words, 1), spec.a_bits,
+                              spec.radix_bits, spec.a_signed)
 
 
 def _digit_matmul_acc(xd, wd, radix_bits: int):
@@ -299,27 +361,10 @@ def _digit_matmul_acc(xd, wd, radix_bits: int):
     return acc
 
 
-def _pack_codes(codes, bits: int):
-    """(bm, bn) int32 codes -> (bits, bm, bn/32) uint32 packed planes.
-
-    The in-kernel serializer: identical word layout to
-    :func:`repro.core.bitops.pack_bitplanes` (lane t -> bit t of the word).
-    """
-    u = jnp.bitwise_and(codes, (1 << bits) - 1).astype(jnp.uint32)
-    r, n = u.shape
-    w = u.reshape(r, n // 32, 32)
-    weights = (jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32))
-    planes = []
-    for b in range(bits):
-        sel = jnp.bitwise_and(jnp.right_shift(w, jnp.uint32(b)), jnp.uint32(1))
-        planes.append(jnp.sum(sel * weights, axis=-1, dtype=jnp.uint32))
-    return jnp.stack(planes)
-
-
 def _kernel_v2(x_ref, w_ref, scale_ref, bias_ref, rs_ref, out_ref, acc_ref,
-               *scratch, spec: SerialSpec, block_k: int, relu: bool,
-               out_dtype, requant: Optional[QuantSpec], emit_packed: bool,
-               n_k: int, cache_weights: bool, cache_acts: bool):
+               *scratch, spec: SerialSpec, relu: bool, out_dtype,
+               requant: Optional[QuantSpec], emit_packed: bool, n_k: int,
+               cache_weights: bool, cache_acts: bool):
     j = pl.program_id(0)   # n-block (outermost)
     i = pl.program_id(1)   # m-block
     kk = pl.program_id(2)  # k-step (innermost, sequential reduction)
@@ -337,11 +382,10 @@ def _kernel_v2(x_ref, w_ref, scale_ref, bias_ref, rs_ref, out_ref, acc_ref,
     if cache_weights:
         @pl.when(i == 0)
         def _fill_w():
-            w_scr[pl.ds(kk, 1)] = _assemble_w_digits(
-                w_ref[...], block_k, spec)[None]
+            w_scr[pl.ds(kk, 1)] = _assemble_w_digits(w_ref, spec)[None]
         wd = w_scr[pl.ds(kk, 1)][0]
     else:
-        wd = _assemble_w_digits(w_ref[...], block_k, spec)
+        wd = _assemble_w_digits(w_ref, spec)
 
     # --- activation digit planes: assembled once per (i, kk), reused for
     # every n-block ------------------------------------------------------
@@ -349,19 +393,17 @@ def _kernel_v2(x_ref, w_ref, scale_ref, bias_ref, rs_ref, out_ref, acc_ref,
         slot = i * n_k + kk
         @pl.when(j == 0)
         def _fill_a():
-            a_scr[pl.ds(slot, 1)] = _assemble_a_digits(
-                x_ref[...], block_k, spec)[None]
+            a_scr[pl.ds(slot, 1)] = _assemble_a_digits(x_ref, spec)[None]
         xd = a_scr[pl.ds(slot, 1)][0]
     else:
-        xd = _assemble_a_digits(x_ref[...], block_k, spec)
+        xd = _assemble_a_digits(x_ref, spec)
 
     acc_ref[...] += _digit_matmul_acc(xd, wd, spec.radix_bits)
 
     @pl.when(kk == n_k - 1)
     def _epilogue():
         acc = acc_ref[...].astype(jnp.float32)
-        out = acc * scale_ref[...].astype(jnp.float32)[None, :]
-        out = out + bias_ref[...].astype(jnp.float32)[None, :]
+        out = acc * scale_ref[...] + bias_ref[...]
         if relu:
             out = jnp.maximum(out, 0.0)
         if requant is None:
@@ -410,8 +452,8 @@ def bitserial_matmul_v2_pallas(
       planes are cached in VMEM scratch — weight planes are unpacked once
       per (n-block, k-step) instead of once per grid step, activation planes
       once per (m-block, k-step),
-    * digits are assembled int8-only via ``digits_from_planes`` (no int32
-      value materialization in VMEM),
+    * digits are assembled by ``digits_from_planes`` (int32 shifts and
+      adds, cast to int8 only for the MXU operands),
     * with ``requant`` + ``emit_packed`` the epilogue fuses the
       quantizer/serializer AND the bit-transpose packer: the kernel emits
       ``(requant.bits, M, ceil(N/32))`` uint32 planes that the next layer's
@@ -452,14 +494,8 @@ def bitserial_matmul_v2_pallas(
                        ((0, 0), (0, mp - m), (0, kp // 32 - kwords)))
     w_packed = jnp.pad(w_packed,
                        ((0, 0), (0, kp // 32 - kwords), (0, np_ - n)))
-    scale = jnp.pad(jnp.broadcast_to(jnp.asarray(scale, jnp.float32), (n,)),
-                    (0, np_ - n))
-    bias = jnp.zeros((n,), jnp.float32) if bias is None else jnp.asarray(
-        bias, jnp.float32)
-    bias = jnp.pad(bias, (0, np_ - n))
-    rs = jnp.broadcast_to(
-        jnp.asarray(1.0 if requant_scale is None else requant_scale,
-                    jnp.float32), (1,))
+    scale, bias = _channel_rows(scale, bias, n, np_)
+    rs = _smem_scalar(requant_scale)
 
     n_i, n_j, n_k = mp // block_m, np_ // block_n, kp // block_k
     grid = (n_j, n_i, n_k)
@@ -468,15 +504,22 @@ def bitserial_matmul_v2_pallas(
     nd_a = bitops.num_digits(spec.a_bits, spec.radix_bits, spec.a_signed)
     # Safety net for callers that pass explicit blocks and bypass the
     # tuner's VMEM filter: the digit-plane caches grow with the *whole*
-    # padded problem (weights: nd_w*Kp*bn; acts: nd_a*Mp*Kp) — drop them
-    # when they cannot fit rather than fail Mosaic compilation. The tuner
-    # (kernels/tuning.py) makes the same call analytically up front.
-    from repro.core.cost_model import TPUConfig
-    _tpu = TPUConfig()
-    budget = int(_tpu.vmem_bytes * _tpu.vmem_budget_frac)
-    if cache_acts and n_i * n_k * nd_a * block_m * block_k > budget // 2:
+    # padded problem (weights: nd_w*Kp*bn; acts: nd_a*Mp*Kp) — drop them,
+    # activations first, when they cannot fit rather than fail Mosaic
+    # compilation. Same estimate and budget as the tuner
+    # (kernels/tuning.py), so a tuner-approved config passes unmodified.
+    from repro.core.cost_model import kernel_vmem_bytes, vmem_budget_bytes
+    budget = vmem_budget_bytes()
+
+    def _vmem(cw, ca):
+        return kernel_vmem_bytes(
+            m, k, n, a_bits=spec.a_bits, w_bits=spec.w_bits, nd_a=nd_a,
+            nd_w=nd_w, bm=block_m, bn=block_n, bk=block_k,
+            cache_weights=cw, cache_acts=ca,
+            out_bits=requant.bits if emit_packed else None)
+    if cache_acts and _vmem(cache_weights, True) > budget:
         cache_acts = False
-    if cache_weights and n_k * nd_w * block_k * block_n > budget // 2:
+    if cache_weights and _vmem(True, cache_acts) > budget:
         cache_weights = False
     scratch = [pltpu.VMEM((block_m, block_n), jnp.int32)]
     if cache_weights:
@@ -498,9 +541,9 @@ def bitserial_matmul_v2_pallas(
                                 lambda j, i, kk: (i, j))
 
     kernel = functools.partial(
-        _kernel_v2, spec=spec, block_k=block_k, relu=relu,
-        out_dtype=out_dtype, requant=requant, emit_packed=emit_packed,
-        n_k=n_k, cache_weights=cache_weights, cache_acts=cache_acts)
+        _kernel_v2, spec=spec, relu=relu, out_dtype=out_dtype,
+        requant=requant, emit_packed=emit_packed, n_k=n_k,
+        cache_weights=cache_weights, cache_acts=cache_acts)
 
     out = pl.pallas_call(
         kernel,
@@ -510,17 +553,17 @@ def bitserial_matmul_v2_pallas(
                          lambda j, i, kk: (0, i, kk)),
             pl.BlockSpec((bw, block_k // 32, block_n),
                          lambda j, i, kk: (0, kk, j)),
-            pl.BlockSpec((block_n,), lambda j, i, kk: (j,)),
-            pl.BlockSpec((block_n,), lambda j, i, kk: (j,)),
-            pl.BlockSpec((1,), lambda j, i, kk: (0,)),
+            pl.BlockSpec((1, block_n), lambda j, i, kk: (0, j)),
+            pl.BlockSpec((1, block_n), lambda j, i, kk: (0, j)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=out_spec,
         out_shape=out_shape,
         scratch_shapes=scratch,
         # scratch reuse spans grid steps along every dimension, so all three
         # must stay sequential on one core ("arbitrary", not "parallel")
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        compiler_params=_compiler_params("arbitrary", "arbitrary",
+                                         "arbitrary"),
         interpret=interpret,
     )(x_packed, w_packed, scale, bias, rs)
     if emit_packed:

@@ -21,8 +21,11 @@ import jax.experimental.pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 from repro.core.quant import QuantSpec, qrange
+from repro.kernels.bitserial_matmul import _pack_codes, _smem_scalar
 
 __all__ = ["quantize_pack_pallas", "quantize_pack_ref"]
+
+_BLOCK_ELEMS = 256 * 2048   # input elements of one default block
 
 
 def quantize_pack_ref(x: jax.Array, scale: jax.Array,
@@ -35,46 +38,47 @@ def quantize_pack_ref(x: jax.Array, scale: jax.Array,
     return bitops.pack_bitplanes(planes, axis=-1)
 
 
-def _kernel(x_ref, scale_ref, out_ref, *, bits: int, signed: bool,
-            block_l: int):
+def _kernel(x_ref, scale_ref, out_ref, *, bits: int, signed: bool):
     qn, qp = qrange(bits, signed)
     x = x_ref[...].astype(jnp.float32)
     codes = jnp.clip(jnp.round(x / scale_ref[0]), qn, qp).astype(jnp.int32)
-    u = jnp.bitwise_and(codes, (1 << bits) - 1).astype(jnp.uint32)
-    r, l = u.shape
-    w = u.reshape(r, l // 32, 32)
-    weights = (jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32))
-    for b in range(bits):
-        bitsel = jnp.bitwise_and(jnp.right_shift(w, jnp.uint32(b)),
-                                 jnp.uint32(1))
-        out_ref[b] = jnp.sum(bitsel * weights, axis=-1, dtype=jnp.uint32)
+    out_ref[...] = _pack_codes(codes, bits)
 
 
 def quantize_pack_pallas(x: jax.Array, scale: jax.Array, spec: QuantSpec, *,
-                         block_r: int = 256, block_l: int = 512,
+                         block_r: Optional[int] = None,
+                         block_l: Optional[int] = None,
                          interpret: bool = False) -> jax.Array:
     """x: (R, L) float; scale: scalar step size. Returns
-    (bits, R, ceil(L/32)) uint32 — identical to the oracle."""
-    r, l = x.shape
-    rp = -(-r // block_r) * block_r
-    lp = -(-l // max(block_l, 32)) * max(block_l, 32)
-    block_l = max(min(block_l, lp), 32)
-    x = jnp.pad(x, ((0, rp - r), (0, lp - l)))
-    scale = jnp.broadcast_to(jnp.asarray(scale, jnp.float32), (1,))
+    (bits, R, ceil(L/32)) uint32 — identical to the oracle.
 
-    kernel = functools.partial(_kernel, bits=spec.bits, signed=spec.signed,
-                               block_l=block_l)
+    ``block_l`` (a multiple of 32) defaults to the whole padded row, so the
+    packed output block ``(bits, block_r, block_l/32)`` spans the array's
+    last dim as Mosaic's tiling rule requires on the chip (a multiple of
+    4096 lanes meets it too). ``block_r`` defaults to 256 rows, fewer for
+    rows wider than 2048 so that one input block stays at 2 MiB of f32
+    and the kernel within its scoped VMEM."""
+    r, l = x.shape
+    block_l = -(-l // 32) * 32 if block_l is None else max(block_l, 32)
+    lp = -(-l // block_l) * block_l
+    block_l = min(block_l, lp)
+    if block_r is None:
+        block_r = max(8, min(256, _BLOCK_ELEMS // block_l // 8 * 8))
+    rp = -(-r // block_r) * block_r
+    x = jnp.pad(x, ((0, rp - r), (0, lp - l)))
+
+    kernel = functools.partial(_kernel, bits=spec.bits, signed=spec.signed)
     out = pl.pallas_call(
         kernel,
         grid=(rp // block_r, lp // block_l),
         in_specs=[
             pl.BlockSpec((block_r, block_l), lambda i, j: (i, j)),
-            pl.BlockSpec((1,), lambda i, j: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((spec.bits, block_r, block_l // 32),
                                lambda i, j: (0, i, j)),
         out_shape=jax.ShapeDtypeStruct((spec.bits, rp, lp // 32),
                                        jnp.uint32),
         interpret=interpret,
-    )(x, scale)
+    )(x, _smem_scalar(scale))
     return out[:, :r, : -(-l // 32)]

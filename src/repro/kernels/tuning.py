@@ -5,7 +5,8 @@ FINN-R and SPEED both show low-precision throughput is won by tuning tile
 geometry per precision: the right block shape depends on the configured
 ``a_bits``/``w_bits`` (they set the packed tile footprints and the number of
 digit-plane matmuls) as much as on M/K/N. This module enumerates candidate
-tiles, filters them by the VMEM working-set estimate, scores the survivors
+tiles, keeps those that meet Mosaic's (8, 128) tiling rule and fit the
+VMEM working-set estimate, scores the survivors
 with the :mod:`repro.core.cost_model` roofline and picks the cheapest —
 including whether the hoisted digit-plane caches (weights / activations)
 fit.
@@ -37,6 +38,7 @@ from repro.core.cost_model import (TPUConfig, conv_kernel_cost,
 
 __all__ = ["TileConfig", "choose_tile", "choose_tile_measured",
            "ConvTileConfig", "choose_conv_tile", "choose_conv_tile_measured",
+           "matmul_tile_ok", "conv_tile_ok",
            "clear_cache", "cache_info", "set_cache_limit",
            "set_persistent_store"]
 
@@ -61,7 +63,7 @@ class TileConfig:
 
 _BM_CANDIDATES = (8, 16, 32, 64, 128, 256, 512)
 _BN_CANDIDATES = (32, 64, 128, 256, 512)     # %32: packed-output word axis
-_BK_CANDIDATES = (32, 64, 128, 256, 512, 1024)
+_BK_CANDIDATES = (32, 64, 128, 256, 512, 1024, 2048, 4096)
 
 # Bounded LRU: a long-lived multi-tenant service facing churning shapes
 # (every new (shape, spec) is one entry) must not grow this without bound.
@@ -154,19 +156,49 @@ def _round_up(x: int, mult: int) -> int:
 
 
 def _candidates(dim: int, options: Tuple[int, ...], mult: int):
-    """Candidate block sizes for one axis: every option up to the first one
-    that covers the (padded) axis in a single block."""
+    """Candidate block sizes for one axis: every option below the (padded)
+    axis, plus one block covering the whole padded axis."""
     cap = _round_up(max(dim, 1), mult)
-    out = [b for b in options if b < cap]
-    covering = [b for b in options if b >= cap]
-    if covering:
-        out.append(covering[0])
-    return out or [options[0]]
+    return [b for b in options if b < cap] + [cap]
+
+
+def _fits_tiling(block: int, dim: int, mult: int) -> bool:
+    """Mosaic's tiling rule for one of a block's last two dims: a multiple
+    of the native tile (8 sublanes, 128 lanes) or the whole (padded) array
+    dim, which is what a block covering ``dim`` becomes."""
+    return block % mult == 0 or block >= dim
+
+
+def matmul_tile_ok(m: int, k: int, n: int, bm: int, bn: int, bk: int, *,
+                   packed_out: bool = False) -> bool:
+    """Whether a v2 matmul tile meets Mosaic's (8, 128) rule on every
+    block of :func:`repro.kernels.bitserial_matmul.bitserial_matmul_v2_pallas`:
+    packed activations ``(a_bits, bm, bk/32)``, packed weights
+    ``(w_bits, bk/32, bn)``, scaler/bias ``(1, bn)`` and the output
+    ``(bm, bn)`` or, packed, ``(bits, bm, bn/32)``. (The weights' ``bk/32``
+    sublane rule is implied by the activations' lane rule.)"""
+    kw, nw = -(-k // 32), -(-n // 32)
+    if bk % 32 or bn % 32:
+        return False
+    return (_fits_tiling(bm, m, 8)
+            and _fits_tiling(bk // 32, kw, 128)
+            and _fits_tiling(bn, n, 128)
+            and (not packed_out or _fits_tiling(bn // 32, nw, 128)))
+
+
+def conv_tile_ok(bco: int) -> bool:
+    """Whether a conv tile meets Mosaic's (8, 128) rule. The conv kernel
+    blocks Co along a leading axis and takes whole rows and channel words,
+    so every block's last two dims are the array's own: only the 32-lane
+    packing word constrains ``block_co``."""
+    return bco % 32 == 0
 
 
 def _enumerate_tiles(m, k, n, spec, *, out_bits, tpu):
-    """All VMEM-feasible matmul tile configs, best-first (modeled cost
-    ascending, larger block volume breaking ties)."""
+    """All VMEM-feasible matmul tile configs that meet the tiling rule,
+    best-first (modeled cost ascending, larger block volume breaking
+    ties). A digit-plane cache is only considered where a later grid step
+    reuses it."""
     nd_a = bitops.num_digits(spec.a_bits, spec.radix_bits, spec.a_signed)
     nd_w = bitops.num_digits(spec.w_bits, spec.radix_bits, spec.w_signed)
     budget = cost_model.vmem_budget_bytes(tpu)
@@ -175,8 +207,14 @@ def _enumerate_tiles(m, k, n, spec, *, out_bits, tpu):
     for bm in _candidates(m, _BM_CANDIDATES, 8):
         for bn in _candidates(n, _BN_CANDIDATES, 32):
             for bk in _candidates(k, _BK_CANDIDATES, 32):
+                if not matmul_tile_ok(m, k, n, bm, bn, bk,
+                                      packed_out=out_bits is not None):
+                    continue
+                n_j, n_i = -(-n // bn), -(-m // bm)
                 for cw, ca in ((True, True), (True, False),
                                (False, True), (False, False)):
+                    if (cw and n_i == 1) or (ca and n_j == 1):
+                        continue
                     kw = dict(a_bits=spec.a_bits, w_bits=spec.w_bits,
                               nd_a=nd_a, nd_w=nd_w, bm=bm, bn=bn, bk=bk,
                               cache_weights=cw, cache_acts=ca,
@@ -192,15 +230,22 @@ def _enumerate_tiles(m, k, n, spec, *, out_bits, tpu):
     return cands
 
 
+def _no_tile(what: str) -> ValueError:
+    return ValueError(f"no {what} tile meets the (8, 128) tiling rule "
+                      "within the VMEM budget")
+
+
 def choose_tile(m: int, k: int, n: int, spec: SerialSpec, *,
                 out_bits: Optional[int] = None,
                 tpu: TPUConfig = TPUConfig()) -> TileConfig:
     """Pick (block_m, block_n, block_k, cache flags) for one matmul shape.
 
     ``out_bits``: set when the fused requant-pack epilogue is used (the
-    packed output constrains ``block_n`` to multiples of 32 — which all
-    candidates already satisfy — and changes the output HBM term).
-    Results are memoized per (shape, spec, out_bits, tpu).
+    packed output constrains ``block_n`` to whole 128-word lane tiles or
+    the whole of N, and changes the output HBM term). Only tiles that meet
+    Mosaic's tiling rule (:func:`matmul_tile_ok`) and fit the VMEM budget
+    are considered; raises ``ValueError`` when none does. Results are
+    memoized per (shape, spec, out_bits, tpu).
     """
     key = (m, k, n, spec, out_bits, tpu)
     hit = _cache_get(key)
@@ -212,12 +257,9 @@ def choose_tile(m: int, k: int, n: int, spec: SerialSpec, *,
         return persisted
 
     cands = _enumerate_tiles(m, k, n, spec, out_bits=out_bits, tpu=tpu)
-    if cands:
-        best = cands[0]
-    else:  # degenerate: nothing fit the budget — smallest tile
-        best = TileConfig(_BM_CANDIDATES[0], _BN_CANDIDATES[0],
-                          _BK_CANDIDATES[0], False, False, float("inf"),
-                          0)
+    if not cands:
+        raise _no_tile(f"matmul ({m}x{k}x{n})")
+    best = cands[0]
     _persist_record(key, "tile", best)
     _cache_put(key, best)
     return best
@@ -251,9 +293,7 @@ def choose_tile_measured(m: int, k: int, n: int, spec: SerialSpec, *,
     cands = _enumerate_tiles(m, k, n, spec, out_bits=out_bits,
                              tpu=tpu)[:max(1, top_k)]
     if not cands:
-        cands = [TileConfig(_BM_CANDIDATES[0], _BN_CANDIDATES[0],
-                            _BK_CANDIDATES[0], False, False,
-                            float("inf"), 0)]
+        raise _no_tile(f"matmul ({m}x{k}x{n})")
     best, best_t = None, None
     for c in cands:                    # analytic order; ties keep rank 1
         t = float(measure(c))
@@ -288,8 +328,10 @@ _BNB_CANDIDATES = (1, 2, 4, 8)               # images per grid step
 
 def _enumerate_conv_tiles(n, h, w, ci, co, *, fh, fw, stride, padding,
                           spec, out_bits, fix_bco, fix_bnb, tpu):
-    """All VMEM-feasible conv tile configs, best-first (modeled cost
-    ascending, larger Co-block × image group breaking ties)."""
+    """All VMEM-feasible conv tile configs that meet the tiling rule,
+    best-first (modeled cost ascending, larger Co-block × image group
+    breaking ties). A digit-plane cache is only considered where a later
+    grid step reuses it."""
     nd_a = bitops.num_digits(spec.a_bits, spec.radix_bits, spec.a_signed)
     nd_w = bitops.num_digits(spec.w_bits, spec.radix_bits, spec.w_signed)
     budget = cost_model.vmem_budget_bytes(tpu)
@@ -298,11 +340,17 @@ def _enumerate_conv_tiles(n, h, w, ci, co, *, fh, fw, stride, padding,
                 else _candidates(co, _BCO_CANDIDATES, 32))
     bnb_opts = ([fix_bnb] if fix_bnb is not None
                 else [b for b in _BNB_CANDIDATES if b <= max(1, n)])
+    ho = (h + 2 * padding - fh) // stride + 1
     cands = []
     for bco in bco_opts:
+        if not conv_tile_ok(bco):
+            continue
         for bnb in bnb_opts:
+            n_j, n_m = -(-co // bco), -(-n // bnb) * ho
             for cw, ca in ((True, True), (True, False),
                            (False, True), (False, False)):
+                if (cw and n_m == 1) or (ca and n_j == 1):
+                    continue
                 kw = dict(fh=fh, fw=fw, stride=stride, padding=padding,
                           a_bits=spec.a_bits, w_bits=spec.w_bits,
                           nd_a=nd_a, nd_w=nd_w, bnb=bnb, bco=bco,
@@ -330,8 +378,9 @@ def choose_conv_tile(n: int, h: int, w: int, ci: int, co: int, *,
     + in-kernel FW walk), so the tuner's degrees of freedom are the
     Co-block width, the image grouping, and whether the digit-plane caches
     fit VMEM. ``fix_bco``/``fix_bnb`` pin one axis (caller override) while
-    the rest is still tuned and VMEM-validated jointly. Memoized per
-    (shape, spec, out_bits, pins, tpu).
+    the rest is still tuned and VMEM-validated jointly. Raises
+    ``ValueError`` when no tile meets the tiling rule within the VMEM
+    budget. Memoized per (shape, spec, out_bits, pins, tpu).
     """
     key = ("conv", n, h, w, ci, co, fh, fw, stride, padding, spec, out_bits,
            fix_bco, fix_bnb, tpu)
@@ -348,11 +397,9 @@ def choose_conv_tile(n: int, h: int, w: int, ci: int, co: int, *,
                                   spec=spec, out_bits=out_bits,
                                   fix_bco=fix_bco, fix_bnb=fix_bnb,
                                   tpu=tpu)
-    if cands:
-        best = cands[0]
-    else:  # degenerate: nothing fit the budget — smallest tile
-        best = ConvTileConfig(fix_bco or _BCO_CANDIDATES[0], fix_bnb or 1,
-                              False, False, float("inf"), 0)
+    if not cands:
+        raise _no_tile(f"conv ({n}x{h}x{w}x{ci}->{co})")
+    best = cands[0]
     _persist_record(key, "conv_tile", best)
     _cache_put(key, best)
     return best
@@ -386,8 +433,7 @@ def choose_conv_tile_measured(n: int, h: int, w: int, ci: int, co: int, *,
                                   fix_bco=None, fix_bnb=None,
                                   tpu=tpu)[:max(1, top_k)]
     if not cands:
-        cands = [ConvTileConfig(_BCO_CANDIDATES[0], 1, False, False,
-                                float("inf"), 0)]
+        raise _no_tile(f"conv ({n}x{h}x{w}x{ci}->{co})")
     best, best_t = None, None
     for c in cands:                    # analytic order; ties keep rank 1
         t = float(measure(c))

@@ -274,7 +274,7 @@ class CNNServer:
             if not prec:
                 raise ValueError(f"artifact must be 'model@precision', "
                                  f"got {artifact!r}")
-            self.graph = None
+            self.graph = self.calib = None
             self.registry = ModelRegistry(backend=backend,
                                           interpret=interpret, store=store)
             self.key = self.registry.register_artifact(model, precision=prec)
@@ -298,7 +298,7 @@ class CNNServer:
                 calib = jax.random.uniform(
                     jax.random.PRNGKey(seed + 1),
                     (calib_batch,) + tuple(int(d) for d in in_shape[1:]))
-            self.graph = graph
+            self.graph, self.calib = graph, calib
             self.registry = ModelRegistry(backend=backend,
                                           interpret=interpret, store=store)
             self.key = self.registry.register_graph(graph.name or "cnn",
@@ -318,10 +318,25 @@ class CNNServer:
         padding buckets (see :meth:`InferenceService.warm_boot`)."""
         return self.service.warm_boot()
 
-    def classify(self, images) -> np.ndarray:
+    def add_precision(self, policy, *, precision: Optional[str] = None):
+        """Serve this server's graph at another precision too; returns the
+        new variant's key for :meth:`classify`. The variant compiles on
+        first use and shares packed weight planes with the variants that
+        quantize them alike — several precisions resident in one registry,
+        no reconfiguration."""
+        if self.graph is None:
+            raise ValueError("an artifact-backed server has no graph to "
+                             "recompile at another precision")
+        return self.registry.register_graph(
+            self.graph.name or "cnn", self.graph, self.calib, policy,
+            precision=precision)
+
+    def classify(self, images, key=None) -> np.ndarray:
         """Logits for a batch of images (NHWC float): per-image requests
-        through the service, re-assembled in order."""
-        futures = self.service.submit_many(self.key, list(np.asarray(images)))
+        to variant ``key`` (default: the first registered) through the
+        service, re-assembled in order."""
+        futures = self.service.submit_many(self.key if key is None else key,
+                                           list(np.asarray(images)))
         return np.stack([f.result() for f in futures])
 
     def metrics(self) -> dict:
@@ -345,8 +360,10 @@ class CNNServer:
 
 
 def _main_cnn(args, cfg) -> None:
-    """CNN arch serving demo: compiled-path classification + cycle report."""
-    backend = args.backend or "xla"
+    """CNN arch serving demo: compiled-path classification + cycle report.
+    The default backend is the Pallas kernels on a TPU, XLA elsewhere."""
+    backend = args.backend or ("pallas_v2" if jax.default_backend() == "tpu"
+                               else "xla")
     if backend == "pallas":
         # the packed conv/matmul ops have no v1 path; v2 is its successor
         print("note: CNN path has no 'pallas' (v1) backend — using pallas_v2")
@@ -603,6 +620,8 @@ def _main_trace(argv) -> None:
 
 def main():
     import sys
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if len(sys.argv) > 1 and sys.argv[1] == "compile":
         # offline code-generator run (kept out of argparse subparsers so
         # the plain `--arch ...` serving invocation stays unchanged)
@@ -655,6 +674,9 @@ def main():
     if args.artifact and not args.store:
         ap.error("--artifact requires --store")
     cfg = get_arch(args.arch).smoke
+    dev = jax.devices()[0]
+    print(f"device: platform={dev.platform} kind={dev.device_kind!r} "
+          f"count={len(jax.devices())}")
     if getattr(cfg, "family", None) == "cnn":
         _main_cnn(args, cfg)  # compiled graph path (the CNN default)
         return
@@ -662,6 +684,8 @@ def main():
         print("note: --store/--artifact apply to compiled CNN archs only")
     from repro.serving import (ContinuousLMEngine, InferenceService,
                                ModelRegistry, supports_continuous)
+    print(f"kernel backend: {args.backend or cfg.policy.backend}"
+          f"{' (the arch policy default)' if not args.backend else ''}")
     max_len = 64
     rng = np.random.RandomState(0)
     if supports_continuous(cfg):

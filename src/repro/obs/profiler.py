@@ -17,7 +17,7 @@ counters — "disabled" is the absence of the object, not a flag check
 Roofline terms (folded in from the retired ``benchmarks/roofline.py``):
 each serial conv/gemm step also gets analytic FLOPs and HBM traffic at
 its packed precision, so summaries report which layers are compute- vs
-memory-bound and the headroom fraction.
+memory-bound on a device whose peaks are in :data:`DEVICE_PEAKS`.
 
 Measured spans are exported as a third Chrome-trace track ("measured"
 process) next to PR 8's wall and virtual-cycle tracks::
@@ -32,12 +32,16 @@ from typing import Dict, List, Optional, Tuple
 
 from repro.obs.tracing import Span, now_ns
 
-# single-chip peaks used for the bound classification (folded in from
-# the seed-era benchmarks/roofline.py, which profiled the pre-compiler
-# dry-run path and is retired by this module)
-PEAK_BF16 = 197e12       # FLOP/s dense bf16
-PEAK_INT8 = 394e12       # FLOP/s int8 (the packed bit-serial planes)
-HBM_BW = 819e9           # bytes/s
+# Published single-chip peaks for the bound classification, keyed by the
+# ``device_kind`` jax reports. Source: Google Cloud documentation, "TPU
+# v5e" (system architecture): 197 TFLOP/s bf16, 393 TOP/s int8, 819 GB/s
+# HBM bandwidth per chip. A device that is not listed (the CPU included)
+# gets no bound classification, never another chip's numbers.
+DEVICE_PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
+                    "hbm_bytes_s": 819e9},
+}
+
 
 # op kinds whose cycles the barrel-controller cost model predicts (the
 # calibration targets); everything else is host-side glue
@@ -77,9 +81,11 @@ def _bits_for(program, name: str) -> Tuple[Optional[int], Optional[int]]:
 
 
 def _roofline_terms(node, batch: int, a_bits: Optional[int],
-                    w_bits: Optional[int]) -> Dict[str, float]:
-    """Analytic FLOPs / HBM bytes / bound classification for one lowered
-    conv or gemm cost node at its packed precision."""
+                    w_bits: Optional[int],
+                    peaks: Optional[Dict[str, float]]) -> Dict:
+    """Analytic FLOPs / HBM bytes for one lowered conv or gemm cost node
+    at its packed precision, and its bound classification against the
+    device's ``peaks`` (none without peaks)."""
     ab = a_bits or 8
     wb = w_bits or 8
     if getattr(node, "kind", None) == "conv2d":
@@ -96,15 +102,13 @@ def _roofline_terms(node, batch: int, a_bits: Optional[int],
                      + batch * node.n * ab) / 8.0
     else:
         return {}
-    t_compute = flops / PEAK_INT8
-    t_memory = bytes_hbm / HBM_BW
-    return {
-        "flops": flops,
-        "bytes_hbm": bytes_hbm,
-        "t_compute_s": t_compute,
-        "t_memory_s": t_memory,
-        "bound": "compute" if t_compute >= t_memory else "memory",
-    }
+    out = {"flops": flops, "bytes_hbm": bytes_hbm}
+    if peaks is not None:
+        t_compute = flops / peaks["int8_ops"]
+        t_memory = bytes_hbm / peaks["hbm_bytes_s"]
+        out.update(t_compute_s=t_compute, t_memory_s=t_memory,
+                   bound="compute" if t_compute >= t_memory else "memory")
+    return out
 
 
 @dataclasses.dataclass
@@ -119,8 +123,8 @@ class StepProfile:
     pred_cycles: int = 0                 # command-stream virtual cycles
     flops: float = 0.0
     bytes_hbm: float = 0.0
-    t_compute_s: float = 0.0
-    t_memory_s: float = 0.0
+    t_compute_s: Optional[float] = None  # None: no peaks for the device
+    t_memory_s: Optional[float] = None
     bound: Optional[str] = None          # "compute" | "memory" | None
     out_shape: Tuple[int, ...] = ()
 
@@ -145,7 +149,13 @@ class ProgramProfile:
     warmup: int
     repeats: int
     mode: str
+    device_kind: str = ""
     steps: List[StepProfile] = dataclasses.field(default_factory=list)
+
+    @property
+    def bounds_measured(self) -> bool:
+        """Whether the device has peaks to classify bounds against."""
+        return DEVICE_PEAKS.get(self.device_kind) is not None
 
     @property
     def total_wall_ns(self) -> float:
@@ -205,8 +215,11 @@ class ProgramProfile:
             "by_precision_us": {k: round(v / 1e3, 1)
                                 for k, v in
                                 sorted(self.by_precision().items())},
-            "compute_bound_layers": n_compute,
-            "memory_bound_layers": n_memory,
+            "device_kind": self.device_kind,
+            "compute_bound_layers": (n_compute if self.bounds_measured
+                                     else None),
+            "memory_bound_layers": (n_memory if self.bounds_measured
+                                    else None),
             "total_flops": sum(s.flops for s in self.steps),
             "total_bytes_hbm": sum(s.bytes_hbm for s in self.steps),
         }
@@ -261,10 +274,12 @@ def profile_program(program, x=None, *, batch: int = 1,
         c_runs = metrics.counter(
             "profiler_runs_total", "profile_program invocations")
 
+    device_kind = next(iter(x.devices())).device_kind
+    peaks = DEVICE_PEAKS.get(device_kind)
     prof = ProgramProfile(
         graph_name=program.graph_name, backend=backend,
         interpret=bool(interpret), batch=batch, warmup=warmup,
-        repeats=repeats, mode=mode)
+        repeats=repeats, mode=mode, device_kind=device_kind)
 
     env = {program.input_name: x}
     for st in program.steps:
@@ -292,7 +307,7 @@ def profile_program(program, x=None, *, batch: int = 1,
         node = nodes.get(st.name)
         if node is not None and st.kind in SERIAL_KINDS:
             rec.__dict__.update(_roofline_terms(node, batch, a_bits,
-                                                w_bits))
+                                                w_bits, peaks))
         prof.steps.append(rec)
         if c_wall is not None:
             c_wall.inc(rec.wall_ns, step=st.name, kind=st.kind)
@@ -311,8 +326,10 @@ def format_profile(profile: ProgramProfile, calibration=None) -> str:
         head += ["ns/cyc", "resid", "flag"]
     rows.append(head)
     for s in profile.steps:
+        bound = s.bound or ("not measured" if s.kind in SERIAL_KINDS
+                            and not profile.bounds_measured else "-")
         row = [s.name, s.kind, s.precision, f"{s.wall_us:10.1f}",
-               f"{s.pred_cycles:12d}", s.bound or "-"]
+               f"{s.pred_cycles:12d}", bound]
         if calibration is not None:
             if s.pred_cycles > 0:
                 r = calibration.residuals.get(s.name)
@@ -332,11 +349,13 @@ def format_profile(profile: ProgramProfile, calibration=None) -> str:
         if i == 0:
             lines.append("  ".join("-" * w for w in widths))
     s = profile.summary()
+    bounds = (f"{s['compute_bound_layers']} compute-bound / "
+              f"{s['memory_bound_layers']} memory-bound serial layers"
+              if profile.bounds_measured else
+              f"bounds not measured (no peaks for {s['device_kind']!r})")
     lines.append("")
     lines.append(
         f"total {s['total_wall_us']:.1f}us over {s['steps']} steps "
         f"(batch={s['batch']}, backend={s['backend']}"
-        f"{', interpret' if s['interpret'] else ''}); "
-        f"{s['compute_bound_layers']} compute-bound / "
-        f"{s['memory_bound_layers']} memory-bound serial layers")
+        f"{', interpret' if s['interpret'] else ''}); {bounds}")
     return "\n".join(lines)

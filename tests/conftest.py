@@ -1,11 +1,7 @@
-"""Test-suite bootstrap: fall back to the bundled hypothesis stub when the
-real library is not installed (bare interpreters / minimal CI images), so
-every tier-1 module still collects and runs. See requirements-dev.txt for
-the preferred full dev environment.
-
-Also implements the two-tier test split: tests marked ``@pytest.mark.slow``
-(soak, e2e, subprocess-mesh) are skipped unless ``--runslow`` (or
-``RUN_SLOW=1``) is given, keeping the default tier-1 run fast."""
+"""Test-suite bootstrap: the two-tier test split. Tests marked
+``@pytest.mark.slow`` (soak, e2e, subprocess-mesh) are skipped unless
+``--runslow`` (or ``RUN_SLOW=1``) is given, keeping the default tier-1 run
+fast."""
 
 import os
 import sys
@@ -18,14 +14,6 @@ sys.path.insert(0, os.path.dirname(__file__))
 # emitted command stream in the suite runs the verifier sandwich. Export
 # REPRO_VERIFY=0 to measure the bare paths.
 os.environ.setdefault("REPRO_VERIFY", "1")
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    import _hypothesis_stub
-
-    _hypothesis_stub._install()
-
 
 def pytest_addoption(parser):
     parser.addoption(

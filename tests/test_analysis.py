@@ -235,6 +235,19 @@ def test_defect_tile_over_vmem_budget(tiny_prog):
     assert ei.value.blame == victim.name
 
 
+def test_defect_tile_breaks_tiling_rule(tiny_prog):
+    steps = list(tiny_prog.steps)
+    idx, victim = next((i, s) for i, s in enumerate(steps)
+                       if s.kind == "conv_packed")
+    attrs = dict(victim.attrs)
+    attrs["tile"] = dict(attrs["tile"], block_co=48)  # not a packing word
+    steps[idx] = dataclasses.replace(victim, attrs=attrs)
+    with pytest.raises(VerifyError) as ei:
+        verify_program(_with_steps(tiny_prog, steps))
+    assert ei.value.check == "tile-rule"
+    assert ei.value.blame == victim.name
+
+
 # ==========================================================================
 # stream analyzer: seeded defects
 # ==========================================================================

@@ -108,3 +108,21 @@ def test_cnn_server_compiled_default():
     assert np.all(np.isfinite(logits))
     rep = server.cycle_report()
     assert "conv1" in rep and "mvu" in rep
+
+
+def test_cnn_server_serves_two_precisions():
+    """One CNNServer, one registry, two resident precisions: each key
+    serves its own Program's logits."""
+    from repro.launch.serve import CNNServer
+    from repro.models.layers import QuantPolicy
+    images = np.random.RandomState(1).rand(2, 32, 32, 3).astype(np.float32)
+    with CNNServer(calib_batch=2, backend="xla") as server:
+        hi = server.add_precision(QuantPolicy(
+            mode="serial", w_bits=4, a_bits=8, radix_bits=7))
+        assert str(hi).endswith("@W4A8")
+        for key in (server.key, hi):
+            want = np.asarray(server.registry.program(key)(images))
+            np.testing.assert_array_equal(server.classify(images, key),
+                                          want)
+        caches = server.metrics()["bucket_caches"]
+        assert {c["replica_devices"][0] for c in caches.values()} == {0}

@@ -95,7 +95,6 @@ def test_compressed_allreduce():
     run_with_devices("""
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        from repro.distributed.compat import shard_map
         from repro.distributed.compression import (compressed_allreduce_mean,
                                                    compress_tree,
                                                    init_error_state)
@@ -103,8 +102,10 @@ def test_compressed_allreduce():
         rng = np.random.RandomState(0)
         g = jnp.asarray(rng.randn(8, 64), jnp.float32)
 
-        f = shard_map(partial(compressed_allreduce_mean, axis_name="data"),
-                      mesh=mesh, in_specs=P("data"), out_specs=P("data"))
+        f = jax.shard_map(
+            partial(compressed_allreduce_mean, axis_name="data"),
+            mesh=mesh, in_specs=P("data"), out_specs=P("data"),
+            check_vma=False)
         out = jax.jit(f)(g)
         ref = jnp.broadcast_to(jnp.mean(g, 0, keepdims=True), g.shape)
         rel = float(jnp.abs(out - ref).max() / (jnp.abs(ref).max() + 1e-9))
@@ -114,8 +115,10 @@ def test_compressed_allreduce():
         def step(err, g):
             red, err = compress_tree({"g": g}, err, "data")
             return err, red["g"]
-        f2 = shard_map(lambda g: step(init_error_state({"g": g}), g)[1],
-                       mesh=mesh, in_specs=P("data"), out_specs=P("data"))
+        f2 = jax.shard_map(
+            lambda g: step(init_error_state({"g": g}), g)[1],
+            mesh=mesh, in_specs=P("data"), out_specs=P("data"),
+            check_vma=False)
         out2 = jax.jit(f2)(g)
         assert np.isfinite(np.asarray(out2)).all()
     """)

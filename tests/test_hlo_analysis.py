@@ -49,10 +49,9 @@ def test_collectives_counted_per_iteration():
             return jax.lax.psum(jnp.tanh(c @ w), "data"), None
         def g(x, ws):
             return jax.lax.scan(body, x, ws)[0]
-        from repro.distributed.compat import shard_map
-        gm = shard_map(g, mesh=mesh,
-                       in_specs=(P(None, None), P(None, None, None)),
-                       out_specs=P(None, None))
+        gm = jax.shard_map(g, mesh=mesh,
+                           in_specs=(P(None, None), P(None, None, None)),
+                           out_specs=P(None, None), check_vma=False)
         x = jax.ShapeDtypeStruct((128, 64), jnp.float32)
         ws = jax.ShapeDtypeStruct((24, 64, 64), jnp.float32)
         comp = jax.jit(gm).lower(x, ws).compile()

@@ -25,7 +25,8 @@ from repro.obs import (Tracer, chrome_trace, fit, fit_samples,
                        format_calibration, format_profile,
                        profile_program, MetricsRegistry)
 from repro.obs import calibrate
-from repro.obs.profiler import SERIAL_KINDS, stream_cycles_by_layer
+from repro.obs.profiler import (SERIAL_KINDS, _roofline_terms,
+                                DEVICE_PEAKS, stream_cycles_by_layer)
 from repro.serving import (ContinuousLMEngine, InferenceService,
                            ModelRegistry, SlotScheduler)
 
@@ -93,7 +94,9 @@ def test_profile_covers_every_step(prog, prof):
     for s in serial:
         assert s.kind in SERIAL_KINDS
         assert s.pred_cycles > 0
-        assert s.bound in ("compute", "memory")
+        # the test device is the CPU, which has no entry in the peak
+        # table: the analytic terms are there, the bound is not measured
+        assert s.bound is None and s.t_compute_s is None
         assert s.flops > 0 and s.bytes_hbm > 0
         assert s.precision == "W2A2"
     # host glue is measured but never priced by the cost model
@@ -117,7 +120,9 @@ def test_profile_summary_and_groupings(prof):
     assert s["steps"] == len(prof.steps)
     assert s["total_wall_us"] == pytest.approx(
         prof.total_wall_ns / 1e3, rel=1e-3)
-    assert s["compute_bound_layers"] + s["memory_bound_layers"] == 2
+    assert s["device_kind"] == "cpu"
+    assert s["compute_bound_layers"] is None
+    assert s["memory_bound_layers"] is None
     assert s["total_flops"] > 0
     assert sum(prof.by_kind().values()) == pytest.approx(
         prof.total_wall_ns)
@@ -125,6 +130,23 @@ def test_profile_summary_and_groupings(prof):
         prof.total_wall_ns)
     table = format_profile(prof)
     assert "c1" in table and "fc" in table and "wall_us" in table
+    assert "not measured" in table
+
+
+@pytest.mark.parametrize("kind", ["cpu", "TPU v4", "TPU v6 lite", ""])
+def test_device_peaks_unknown_kind_gives_no_bound(prog, kind):
+    """Only a device kind in the peak table is classified; any other
+    device, the CPU included, gets no bound and never v5e's numbers."""
+    assert DEVICE_PEAKS.get(kind) is None
+    node = next(n for n in prog.cost_nodes if n.name == "c1")
+    terms = _roofline_terms(node, 4, 2, 2, DEVICE_PEAKS.get(kind))
+    assert terms["flops"] > 0 and "bound" not in terms
+
+
+def test_device_peaks_v5e():
+    peaks = DEVICE_PEAKS["TPU v5 lite"]
+    assert peaks == {"bf16_flops": 197e12, "int8_ops": 393e12,
+                     "hbm_bytes_s": 819e9}
 
 
 def test_profile_metrics_registry_opt_in(prog):
