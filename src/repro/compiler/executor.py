@@ -142,12 +142,15 @@ def make_runner(program, *, backend: Optional[str] = None,
             if fn is None:
                 raise KeyError(f"no executor for step kind {st.kind!r}")
             args = [env[i] for i in st.inputs]
-            if st.kind == "add":
-                env[st.output] = fn(st, params.get(st.name, {}), *args,
-                                    backend, interpret)
-            else:
-                env[st.output] = fn(st, params.get(st.name, {}), args[0],
-                                    backend, interpret)
+            # the step's name on every op it lowers to, so a device trace
+            # or HLO dump names the layer
+            with jax.named_scope(st.name):
+                if st.kind == "add":
+                    env[st.output] = fn(st, params.get(st.name, {}), *args,
+                                        backend, interpret)
+                else:
+                    env[st.output] = fn(st, params.get(st.name, {}),
+                                        args[0], backend, interpret)
         return env[output_name]
 
     return run
@@ -298,6 +301,9 @@ class BucketedRunner:
             "runner_bucket_compiles_total", "new (bank, bucket) jit keys")
         self._c_hits = self.metrics_registry.counter(
             "runner_bucket_hits_total", "warm (bank, bucket) jit hits")
+        self._c_padded = self.metrics_registry.counter(
+            "runner_padded_rows_total",
+            "zero rows added to fill a batch to its bucket")
 
     @property
     def compiles(self) -> int:
@@ -308,7 +314,16 @@ class BucketedRunner:
         return int(self._c_hits.value())
 
     def __call__(self, x, *, bank: Optional[int] = None):
-        x = jnp.asarray(x)
+        return self.launch(x, bank=bank)[0]
+
+    def launch(self, x, *, bank: Optional[int] = None):
+        """Put ``x`` on the device, pad it to its bucket and dispatch the
+        Program, without waiting for either.
+
+        Returns ``(y, x_dev, padded)``: the output rows (in flight), the
+        input as put on the device (before padding; ready once the
+        host-to-device transfer is done), and the zero rows added."""
+        x = x_dev = jnp.asarray(x)
         n = x.shape[0]
         b = bucket_for(n, self.max_batch, self._multiple)
         if b != n:
@@ -328,11 +343,14 @@ class BucketedRunner:
             else:
                 self._seen.add(key)
                 self._c_compiles.inc()
+            self._c_padded.inc(b - n)
         if self._sharded is not None:
-            return self._sharded(x)[:n]
-        if self._banks is not None:
-            return self._fn(self._bank_params[bank], x)[:n]
-        return self._fn(self.program.params, x)[:n]
+            y = self._sharded(x)
+        elif self._banks is not None:
+            y = self._fn(self._bank_params[bank], x)
+        else:
+            y = self._fn(self.program.params, x)
+        return y[:n], x_dev, b - n
 
     def warmup(self, example_shape=None) -> int:
         """Compile every (bucket, bank) ahead of traffic; returns the

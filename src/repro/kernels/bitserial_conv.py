@@ -307,6 +307,7 @@ def bitserial_conv2d_v2_pallas(
         compiler_params=_compiler_params("arbitrary", "arbitrary",
                                          "arbitrary"),
         interpret=interpret,
+        name="bitserial_conv2d_v2",
     )(x_packed, w_packed, scale, bias, rs)
     # Co-blocks back onto the channel axis
     out = jnp.moveaxis(out, -5, -2)

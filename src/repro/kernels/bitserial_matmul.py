@@ -314,6 +314,7 @@ def bitserial_matmul_pallas(
         compiler_params=_compiler_params("parallel", "parallel",
                                          "arbitrary"),
         interpret=interpret,
+        name="bitserial_matmul",
     )(x, w_packed, scale, bias)
     return out[:m, :n]
 
@@ -565,6 +566,7 @@ def bitserial_matmul_v2_pallas(
         compiler_params=_compiler_params("arbitrary", "arbitrary",
                                          "arbitrary"),
         interpret=interpret,
+        name="bitserial_matmul_v2",
     )(x_packed, w_packed, scale, bias, rs)
     if emit_packed:
         return out[:, :m, : -(-n // 32)]

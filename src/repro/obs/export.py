@@ -72,12 +72,17 @@ def chrome_trace(tracer: Tracer, *, extra_spans: Iterable[Span] = ()
                 "args": args,
             })
         if s.cycle_start is not None:
-            # request-scoped spans get their own cycle row; tracker spans
-            # (trace_id 0) keep their bank/hart occupancy track
+            # request- and batch-scoped spans get their own cycle row;
+            # tracker spans (trace_id 0) keep their bank/hart occupancy track
+            if s.trace_id:
+                tid = f"req-{s.trace_id}"
+            elif "batch_id" in args:
+                tid = f"batch-{args['batch_id']}"
+            else:
+                tid = s.track or "events"
             events.append({
                 "name": s.name, "ph": "X", "pid": "virtual-cycles",
-                "tid": (f"req-{s.trace_id}" if s.trace_id
-                        else (s.track or "events")),
+                "tid": tid,
                 "ts": float(s.cycle_start),
                 "dur": float(max(s.cycle_end - s.cycle_start, 0)),
                 "args": args,
@@ -166,16 +171,14 @@ PHASES = ("queue", "schedule", "execute", "finalize")
 def trace_summary(trace_json: Dict, *, top_k: int = 10) -> List[Dict]:
     """Digest a saved Chrome trace into the top-k slowest requests with a
     per-phase wall-time breakdown. Reads only the wall-domain events, so
-    it works on any file :func:`write_chrome_trace` produced."""
+    it works on any file :func:`write_chrome_trace` produced. A request's
+    batch phases (events with a ``batch_id`` and no ``trace_id``) join it
+    through the ``batch_id`` its own spans carry."""
     per_req: Dict[int, Dict] = {}
-    for ev in trace_json.get("traceEvents", []):
-        if ev.get("pid") != "wall" or ev.get("ph") != "X":
-            continue
-        tid = ev.get("args", {}).get("trace_id")
-        if not tid:
-            continue
-        r = per_req.setdefault(tid, {"trace_id": tid, "phases": {},
-                                     "total_us": 0.0, "cycles": 0})
+    per_batch: Dict[int, List[Dict]] = {}
+    links: Dict[int, int] = {}
+
+    def add(r, ev):
         name = ev["name"]
         dur = float(ev.get("dur", 0.0))
         r["phases"][name] = r["phases"].get(name, 0.0) + dur
@@ -184,6 +187,23 @@ def trace_summary(trace_json: Dict, *, top_k: int = 10) -> List[Dict]:
         cyc = ev.get("args", {}).get("cycles")
         if cyc and name != "decode_step":
             r["cycles"] += int(cyc)
+
+    for ev in trace_json.get("traceEvents", []):
+        if ev.get("pid") != "wall" or ev.get("ph") != "X":
+            continue
+        args = ev.get("args", {})
+        tid = args.get("trace_id")
+        if not tid:
+            if "batch_id" in args:
+                per_batch.setdefault(args["batch_id"], []).append(ev)
+            continue
+        if "batch_id" in args:
+            links[tid] = args["batch_id"]
+        add(per_req.setdefault(tid, {"trace_id": tid, "phases": {},
+                                     "total_us": 0.0, "cycles": 0}), ev)
+    for tid, bid in links.items():
+        for ev in per_batch.get(bid, ()):
+            add(per_req[tid], ev)
     rows = sorted(per_req.values(), key=lambda r: -r["total_us"])[:top_k]
     return rows
 

@@ -3,8 +3,12 @@ domains.
 
 A *trace* is one request's life: ``submit`` → queue wait in the
 ``DynamicBatcher`` → ``SlotScheduler`` booking → ``BucketedRunner`` /
-``ContinuousLMEngine`` execute → finalize. Each phase is one
-:class:`Span`. Spans carry **two clock domains**:
+``ContinuousLMEngine`` execute → finalize. The queue wait is the
+request's own :class:`Span`; the phases after it belong to the
+micro-batch the request rode in, and are recorded once per batch
+(:meth:`Tracer.batch_span`, ``args["batch_id"]``), linked to the request
+through the ``batch_id`` its queue span carries. Spans carry **two clock
+domains**:
 
 * **wall** — ``time.perf_counter_ns()`` stamps, the thread-level truth of
   where time went in the Python serving stack;
@@ -151,6 +155,18 @@ class Tracer:
                                 cycle_start=cycle_start,
                                 cycle_end=cycle_end, track=track,
                                 args=args or None))
+
+    def batch_span(self, batch_id: int, name: str, t0_ns: int, t1_ns: int,
+                   *, cycle_start: Optional[int] = None,
+                   cycle_end: Optional[int] = None,
+                   track: Optional[str] = None, **args) -> None:
+        """Emit one finished phase of micro-batch ``batch_id``. The caller
+        decides whether the batch is recorded: with the tracer on, when any
+        of its requests is sampled."""
+        args["batch_id"] = batch_id
+        self._spans.append(Span(0, name, t0_ns, t1_ns,
+                                cycle_start=cycle_start,
+                                cycle_end=cycle_end, track=track, args=args))
 
     def cycle_span(self, name: str, cycle_start: int, cycle_end: int, *,
                    track: str, trace_id: int = 0, **args) -> None:

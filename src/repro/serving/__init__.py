@@ -31,11 +31,13 @@ Observability (:mod:`repro.obs`): the service threads one
 :class:`~repro.obs.metrics.MetricsRegistry` and one
 :class:`~repro.obs.tracing.Tracer` through every component it constructs —
 every legacy ``metrics()``/``stats()`` dict is registry-backed, each
-request carries a trace context (queue/schedule/execute/finalize spans in
-wall-ns *and* virtual MVU cycles), and the scheduler keeps per-bank HPM
-counter files (per-hart busy/xfer/issue/stall with per-tag/per-precision
-attribution). Export via :func:`repro.obs.write_chrome_trace` (Perfetto)
-and :func:`repro.obs.prometheus_text` over ``service.registries()``.
+request carries a trace context (its queue span, linked by ``batch_id``
+to its micro-batch's form/schedule/execute/finalize spans, recorded once
+per batch in wall-ns *and* virtual MVU cycles), and the scheduler keeps
+per-bank HPM counter files (per-hart busy/xfer/issue/stall with
+per-tag/per-precision attribution). Export via
+:func:`repro.obs.write_chrome_trace` (Perfetto) and
+:func:`repro.obs.prometheus_text` over ``service.registries()``.
 """
 
 from repro.serving.batcher import (DynamicBatcher, MicroBatch, QueueFull,

@@ -43,11 +43,13 @@ request has resolved.
 from __future__ import annotations
 
 import collections
+import itertools
 import threading
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Dict, List, Optional
 
+import jax
 import numpy as np
 
 from repro.compiler import executor
@@ -61,6 +63,8 @@ from repro.serving.registry import ModelKey, ModelRegistry
 from repro.serving.scheduler import SlotScheduler
 
 __all__ = ["InferenceService"]
+
+_WORKER = "serving-worker"
 
 
 class InferenceService:
@@ -140,6 +144,7 @@ class InferenceService:
         self._pend_lock = threading.Condition()
         self._pending = 0    # guarded-by: _pend_lock
         self._batch_seq = 0  # guarded-by: _mlock
+        self._batch_ids = itertools.count(1)   # worker thread only
         # guards everything metrics() reads while the worker writes it
         # (latency deque, runner dict, straggler window, counters — with a
         # finalize pool several completions may land concurrently)
@@ -168,8 +173,8 @@ class InferenceService:
             self._pool = ThreadPoolExecutor(
                 max_workers=self.n_banks,
                 thread_name_prefix="serving-finalize")
-        self._thread = threading.Thread(target=self._loop,
-                                        name="serving-worker", daemon=True)
+        self._thread = threading.Thread(target=self._loop, name=_WORKER,
+                                        daemon=True)
         self._thread.start()
         return self
 
@@ -299,34 +304,52 @@ class InferenceService:
 
     def _loop(self) -> None:
         while not self._stop.is_set():
+            t_form = now_ns()
             mb = self.batcher.next_batch(timeout=0.05,
                                          max_batch_for=self._max_batch_for)
             if mb is None:
                 continue
-            self._run_batch(mb)
+            self._run_batch(mb, t_form)
 
-    def _run_batch(self, mb: MicroBatch) -> None:
+    def _run_batch(self, mb: MicroBatch, t_form: int) -> None:
         t0 = time.perf_counter()
-        marks = {"batch": now_ns()}
+        bid = next(self._batch_ids)
+        marks = {"batch_id": bid, "form": t_form, "batch": now_ns(),
+                 "traced": self.tracer.enabled and any(
+                     r.trace is not None and r.trace.sampled
+                     for r in mb.requests)}
+        # one profiler step per batch, from formed to finalized: with a
+        # jax.profiler session open the batch appears in the trace's host
+        # plane under its id, on the trace's own clock
+        marks["step"] = step = jax.profiler.StepTraceAnnotation(
+            "serve_batch", step_num=bid)
+        step.__enter__()
         try:
             pending, admission = self._dispatch(mb, marks)
         except WorkerFailure as e:
+            step.__exit__(None, None, None)
             # transient bank loss on the serving path: requeue the batch's
             # requests (bounded per request by max_retries) rather than
             # failing them — a flaky bank costs latency, not errors
             self._requeue_or_fail(mb, e)
             return
         except BaseException as e:  # noqa: BLE001 — worker must survive
+            step.__exit__(None, None, None)
             self._fail_batch(mb, e)
             return
         if self._pool is None:
             self._finalize(mb, pending, admission, t0, marks)
-        else:
-            # multi-bank: device work is in flight (jax dispatch is async);
-            # materialization + future resolution move off the worker so
-            # the next micro-batch can start on another bank immediately
+            return
+        # multi-bank: device work is in flight (jax dispatch is async);
+        # materialization + future resolution move off the worker so the
+        # next micro-batch can start on another bank immediately. The
+        # batch's profiler step then ends on the finalize thread.
+        try:
             self._pool.submit(self._finalize, mb, pending, admission, t0,
                               marks)
+        except BaseException:
+            step.__exit__(None, None, None)
+            raise
 
     def _fail_batch(self, mb: MicroBatch, e: BaseException) -> None:
         for r in mb.requests:
@@ -360,8 +383,8 @@ class InferenceService:
     def _dispatch(self, mb: MicroBatch, marks: Dict):
         """Book the batch and launch its device work (no host sync).
 
-        ``marks`` collects the phase boundary timestamps (ns) the finalize
-        step turns into queue/schedule/execute spans."""
+        ``marks`` collects the phase boundary timestamps (ns) that
+        :meth:`_emit_spans` turns into the batch's spans."""
         entry = self.registry.entry(mb.key)
         if entry.kind == "callable":
             if getattr(entry.fn, "books_own_cycles", False):
@@ -394,24 +417,51 @@ class InferenceService:
                                          program=runner.program)
         marks["exec"] = now_ns()
         x = np.stack([np.asarray(r.payload) for r in mb.requests])
+        marks["stack"] = now_ns()
         bank = (admission.bank
                 if admission is not None and runner.placement == "banked"
                 else None)
-        return ("array", runner(x, bank=bank)), admission
+        y, x_dev, marks["padded"] = runner.launch(x, bank=bank)
+        marks["launch"] = now_ns()
+        return ("array", (y, x_dev)), admission
 
     def _finalize(self, mb: MicroBatch, pending, admission,
                   t0: float, marks: Dict) -> None:
         """Materialize the dispatched batch and resolve its futures."""
         try:
+            t_fin_done = self._resolve(mb, pending, admission, t0, marks)
+        finally:
+            marks["step"].__exit__(None, None, None)
+        if t_fin_done is not None:
+            self._emit_spans(mb, admission, marks, t_fin_done)
+            self._mark_done(len(mb.requests))
+
+    def _resolve(self, mb: MicroBatch, pending, admission, t0: float,
+                 marks: Dict) -> Optional[int]:
+        """Wait for the batch's rows and resolve its futures; returns when
+        that ended (ns), or None when the batch failed or was requeued."""
+        try:
             kind, val = pending
-            results = val if kind == "list" else list(np.asarray(val))
+            if kind == "list":
+                results = val
+            else:
+                y, x_dev = val
+                if marks["traced"]:
+                    # the program is already queued behind its input, so
+                    # these waits hold nothing back: they split the wait
+                    # for the rows into transfer, device and read-back
+                    x_dev.block_until_ready()
+                    marks["in"] = now_ns()
+                    y.block_until_ready()
+                    marks["out"] = now_ns()
+                results = list(np.asarray(y))
         except WorkerFailure as e:
             self._requeue_or_fail(mb, e)
-            return
+            return None
         except BaseException as e:  # noqa: BLE001 — pool must survive
             self._fail_batch(mb, e)
-            return
-        t_exec_done = now_ns()
+            return None
+        marks["done"] = now_ns()
         dt = time.perf_counter() - t0
         self.scheduler.complete(admission, dt)
         done = time.perf_counter()
@@ -425,41 +475,62 @@ class InferenceService:
         for r, y in zip(mb.requests, results):
             r.future.set_result(y)
         self._c_completed.inc(len(mb.requests))
-        self._emit_spans(mb, admission, marks, t_exec_done, now_ns())
-        self._mark_done(len(mb.requests))
+        return now_ns()
 
     def _emit_spans(self, mb: MicroBatch, admission, marks: Dict,
-                    t_exec_done: int, t_fin_done: int) -> None:
-        """Turn one batch's phase boundaries into per-request spans.
+                    t_fin_done: int) -> None:
+        """Turn one batch's phase boundaries into its spans: one set of
+        batch spans, and one ``queue`` span per request (submit times
+        differ) that links the request to them through ``batch_id``. The
+        last batch span, ``emit``, times this call.
 
-        Every request in the batch shares the batch's phase timestamps
-        (they rode the same dispatch); the queue span is per-request
-        (submit time differs). The tracer drops everything for unsampled
-        traces, so this is a handful of attribute checks when sampling."""
-        tr = self.tracer
-        if not tr.enabled:
+        The batch is recorded when any of its requests is sampled; each
+        request's queue span only when that request is."""
+        if not marks["traced"]:
             return
-        worker = threading.current_thread().name
-        t_batch = marks["batch"]
-        t_sched = marks.get("sched")
-        t_exec = marks.get("exec", t_batch)
+        tr = self.tracer
+        here = threading.current_thread().name
+        bid = marks["batch_id"]
+        t_exec, t_done = marks["exec"], marks["done"]
         cyc0 = admission.start_cycle if admission is not None else None
         cyc1 = admission.finish_cycle if admission is not None else None
-        # batch-constant span args, hoisted off the per-request loop
-        key_s = str(mb.key)
-        banks = list(admission.banks) if admission is not None else None
+        args = {"requests": mb.size, "padded": marks.get("padded", 0)}
+        tr.batch_span(bid, "form", marks["form"], marks["batch"],
+                      track=_WORKER, **args)
+        if "sched" in marks:
+            tr.batch_span(bid, "schedule", marks["sched"], t_exec,
+                          track=_WORKER, cycle_start=cyc0, cycle_end=cyc1,
+                          bank=(list(admission.banks)
+                                if admission is not None else None), **args)
+        tr.batch_span(bid, "execute", t_exec, t_done, track=here,
+                      cycle_start=cyc0, cycle_end=cyc1, **args)
+        if "launch" in marks:
+            t_stack, t_launch = marks["stack"], marks["launch"]
+            tr.batch_span(bid, "stack", t_exec, t_stack, track=_WORKER,
+                          **args)
+            tr.batch_span(bid, "launch", t_stack, t_launch, track=_WORKER,
+                          **args)
+            # the device put starts the launch; transfer and device are
+            # what the host waited on, on a row of their own
+            dev = here + "/device"
+            tr.batch_span(bid, "transfer", t_stack, marks["in"], track=dev,
+                          **args)
+            tr.batch_span(bid, "device", marks["in"], marks["out"],
+                          track=dev, **args)
+            tr.batch_span(bid, "readback", marks["out"], t_done, track=here,
+                          **args)
+        tr.batch_span(bid, "finalize", t_done, t_fin_done, track=here,
+                      **args)
+        key_s, n, t_batch = str(mb.key), mb.size, marks["batch"]
         for r in mb.requests:
             ctx = r.trace
             if ctx is None or not ctx.sampled:
                 continue
-            tr.span(ctx, "queue", ctx.t_submit_ns, t_batch, track=worker,
-                    key=key_s, batch=mb.size)
-            if t_sched is not None:
-                tr.span(ctx, "schedule", t_sched, t_exec, track=worker,
-                        cycle_start=cyc0, cycle_end=cyc1, bank=banks)
-            tr.span(ctx, "execute", t_exec, t_exec_done, track=worker,
-                    cycle_start=cyc0, cycle_end=cyc1)
-            tr.span(ctx, "finalize", t_exec_done, t_fin_done, track=worker)
+            tr.span(ctx, "queue", ctx.t_submit_ns, t_batch, track=_WORKER,
+                    key=key_s, batch=n, batch_id=bid)
+        # the emission itself, so the host's time between one batch's
+        # finalize and the next batch's form has a name too
+        tr.batch_span(bid, "emit", t_fin_done, now_ns(), track=here, **args)
 
     # legacy attribute surface, now registry-backed
     @property

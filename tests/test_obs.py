@@ -375,8 +375,9 @@ def test_service_trace_end_to_end_perfetto(tmp_path):
     wall_names = {e["name"] for e in ev if e["pid"] == "wall"}
     assert set(PHASES) <= wall_names
     # schedule+execute carry the booked cycle window -> cycle-domain rows
+    # (recorded once per micro-batch, on the batch's own row)
     req_cyc = [e for e in ev if e["pid"] == "virtual-cycles"
-               and str(e["tid"]).startswith("req-")]
+               and str(e["tid"]).startswith(("req-", "batch-"))]
     assert {e["name"] for e in req_cyc} >= {"schedule", "execute"}
     assert all(e["dur"] > 0 for e in req_cyc)
     # the scheduler's per-hart occupancy rows (both harts of mixed_stream)
