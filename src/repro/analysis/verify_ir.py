@@ -175,8 +175,7 @@ def _tile_vmem(step, cost_node, calib_batch: int, budget: int,
             stride=cost_node.stride, padding=cost_node.padding,
             a_bits=spec.a_bits, w_bits=spec.w_bits, nd_a=nd_a, nd_w=nd_w,
             bnb=tile["block_nb"], bco=tile["block_co"],
-            cache_weights=tile["cache_weights"],
-            cache_acts=tile["cache_acts"], out_bits=out_bits)
+            cache_weights=tile["cache_weights"], out_bits=out_bits)
     else:
         used = cost_model.kernel_vmem_bytes(
             calib_batch, step.attrs["k"], cost_node.n,

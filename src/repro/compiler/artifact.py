@@ -53,7 +53,9 @@ __all__ = ["ArtifactError", "ArtifactStore", "array_digest",
            "FORMAT", "VERSION"]
 
 FORMAT = "repro-program-artifact"
-VERSION = 1
+# 2: conv tiles lost ``cache_acts`` (the kernel's row ring replaced it) and
+# record the activation digit columns a call serves and assembles
+VERSION = 2
 
 
 class ArtifactError(RuntimeError):

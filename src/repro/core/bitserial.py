@@ -141,11 +141,12 @@ def serial_matmul(x: jax.Array, w: jax.Array, spec: SerialSpec) -> jax.Array:
 
 
 def digits_from_planes(planes: jax.Array, bits: int, radix_bits: int,
-                       signed: bool) -> jax.Array:
+                       signed: bool, dtype=jnp.int8) -> jax.Array:
     """Assemble int8 digit planes directly from {0,1} bit planes
     (``planes``: (bits, ...), any integer dtype). The Pallas kernels run
     this per VMEM tile, so it sticks to what the TPU vector unit supports:
-    shifts and adds in int32, with one cast to int8 per digit for the MXU.
+    shifts and adds in int32, with one cast to ``dtype`` per digit (int8
+    for the MXU; int32 keeps the digits for 32-bit strided loads).
 
     Signed top digit: the MSB plane enters with negative weight
     −2^{bits−1−lo} (two's complement), so every digit fits int8 for
@@ -163,7 +164,7 @@ def digits_from_planes(planes: jax.Array, bits: int, radix_bits: int,
                 d = -term if d is None else d - term
             else:
                 d = term if d is None else d + term
-        out.append(d.astype(jnp.int8))
+        out.append(d.astype(dtype))
     return jnp.stack(out)
 
 

@@ -30,7 +30,9 @@ __all__ = ["HWConfig", "ConvLayer", "LinearLayer", "layer_cycles",
            "pipelined_fps", "distributed_fps", "network_cycles",
            "RESNET9_CIFAR10", "CNV_CIFAR10", "resnet50_layers",
            "TPUConfig", "vmem_budget_bytes", "kernel_vmem_bytes",
-           "kernel_cost", "conv_kernel_vmem_bytes", "conv_kernel_cost"]
+           "kernel_cost", "conv_row_cols", "conv_ring_lanes",
+           "conv_act_columns",
+           "conv_kernel_vmem_bytes", "conv_vpu_ops", "conv_kernel_cost"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -276,77 +278,143 @@ def _conv_geom(n, h, w, ci, fh, fw, stride, padding, bnb, bco, co):
     return ho, wo, hp, wp, ciw, n_nb, n_j
 
 
+def conv_row_cols(fw: int, wo: int, stride: int, wp: int) -> int:
+    """Columns of one padded input row that the conv kernel's row ring
+    assembles per image: the whole row where the FW tap windows overlap
+    (``fw >= stride``), else only the windows' own columns, side by side."""
+    return wp if fw >= stride else fw * wo
+
+
+def _ring_rows(ho: int, fh: int, stride: int) -> int:
+    """Input rows the conv kernel's row ring assembles (and fetches) per
+    image block: the FH rows of its first output row, then the
+    ``min(stride, fh)`` rows each later output row adds."""
+    return fh + (ho - 1) * min(stride, fh)
+
+
+def conv_ring_lanes(ci_pad: int) -> int:
+    """Lane width of one slab of the conv kernel's row ring: the channel
+    lanes split into slabs of at most 128, since Mosaic's strided loads
+    (the tap windows of a strided conv) take a base of one lane tile."""
+    return ci_pad if ci_pad <= 128 else math.gcd(ci_pad, 128)
+
+
+def conv_act_columns(n: int, h: int, w: int, ci: int, co: int, *, fh: int,
+                     fw: int, stride: int, padding: int, bnb: int,
+                     bco: int) -> tuple:
+    """``(served, assembled)`` activation digit columns of one conv call,
+    counted in (image, column) pairs: ``served`` by the FH x FW tap
+    windows (each ``wo`` columns wide), ``assembled`` by the row ring,
+    which unpacks an input row when its first tap reads it
+    (:func:`_ring_rows` rows per image block). Their ratio is how often each
+    assembled digit is reused (about 8 for a 32x32 3x3 stride-1 conv; 1
+    where every tap window is unpacked on its own)."""
+    ho, wo, hp, wp, ciw, n_nb, n_j = _conv_geom(
+        n, h, w, ci, fh, fw, stride, padding, bnb, bco, co)
+    images = n_j * n_nb * bnb
+    return (images * ho * fh * fw * wo,
+            images * _ring_rows(ho, fh, stride)
+            * conv_row_cols(fw, wo, stride, wp))
+
+
 def conv_kernel_vmem_bytes(n: int, h: int, w: int, ci: int, co: int, *,
                            fh: int, fw: int, stride: int, padding: int,
                            a_bits: int, w_bits: int, nd_a: int, nd_w: int,
                            bnb: int, bco: int, cache_weights: bool,
-                           cache_acts: bool,
                            out_bits: Optional[int] = None) -> int:
     """VMEM working set of one implicit-GEMM conv invocation (bytes,
     tile-padded).
 
     Same accounting as :func:`kernel_vmem_bytes`: BlockSpec-pipelined
-    buffers double-buffered (x2); scratches (accumulator + digit-plane
-    caches) single instances; the assembled tap planes of one step and
-    the packer's operands live.
+    buffers double-buffered (x2); scratches (accumulator, the FH-slot
+    row ring of int32 activation digits, the weight digit cache) single
+    instances; one row's assembly, the tap windows of one step and the
+    packer's operands live.
     """
     ho, wo, hp, wp, ciw, n_nb, n_j = _conv_geom(
         n, h, w, ci, fh, fw, stride, padding, bnb, bco, co)
     ci_pad = ciw * 32
     rows = bnb * wo                               # GEMM rows per step
+    ring_rows = bnb * conv_row_cols(fw, wo, stride, wp)
     x_tile = _tiled((a_bits * bnb, wp, ciw), 4)   # one packed input row
     w_tile = _tiled((w_bits * fw, ciw, bco), 4)   # one packed filter-row tap
     out_tile = (_tiled((out_bits * bnb, wo, bco // 32), 4) if out_bits
                 else _tiled((bnb, wo, bco), 4))
     pipelined = 2 * (x_tile + w_tile + out_tile + 2 * _tiled((1, bco), 4))
     acc = _tiled((rows, bco), 4)
-    live = (fw * nd_a * _tiled((rows, ci_pad), 1)
-            + 3 * _tiled((rows, ci_pad), 4)
+    lanes = conv_ring_lanes(ci_pad)
+    ring = _tiled((fh * nd_a * (ci_pad // lanes), ring_rows, lanes), 4)
+    live = ((nd_a + 3) * _tiled((ring_rows, ci_pad), 4)
+            + fw * nd_a * _tiled((rows, ci_pad), 1)
+            + nd_a * _tiled((rows, ci_pad), 4)
             + fw * nd_w * _tiled((ci_pad, bco), 1)
             + 3 * _tiled((ci_pad, bco), 4)
             + (_packer_bytes(rows, bco, out_bits) if out_bits else 0))
     w_scr = _tiled((fh * nd_w * fw, ci_pad, bco), 1) if cache_weights else 0
-    a_scr = (_tiled((n_nb * ho * fh * fw * nd_a, rows, ci_pad), 1)
-             if cache_acts else 0)
-    return pipelined + acc + live + w_scr + a_scr
+    return pipelined + acc + ring + live + w_scr
+
+
+def conv_vpu_ops(n: int, h: int, w: int, ci: int, co: int, *, fh: int,
+                 fw: int, stride: int, padding: int, a_bits: int,
+                 w_bits: int, nd_a: int, nd_w: int, bnb: int, bco: int,
+                 cache_weights: bool,
+                 out_bits: Optional[int] = None) -> dict:
+    """Vector-unit element operations of one conv call, by term:
+    ``weights`` (weight-tap digit assembly: once per (Co-block, f_h) when
+    cached, else every grid step), ``act_rows`` (unpacking the a_bits
+    planes of each row the ring assembles into its digits: once per
+    assembled column, not once per tap window), ``act_windows`` (the
+    int8 cast of every tap window's digits) and ``epilogue``."""
+    ho, wo, hp, wp, ciw, n_nb, n_j = _conv_geom(
+        n, h, w, ci, fh, fw, stride, padding, bnb, bco, co)
+    ci_pad = ciw * 32
+    n_m = n_nb * ho
+    served, assembled = conv_act_columns(
+        n, h, w, ci, co, fh=fh, fw=fw, stride=stride, padding=padding,
+        bnb=bnb, bco=bco)
+    tap_work = (w_bits + nd_w) * fw * ci_pad * bco
+    return {
+        "weights": tap_work * n_j * fh * (1 if cache_weights else n_m),
+        "act_rows": (a_bits + nd_a) * assembled * ci_pad,
+        "act_windows": nd_a * served * ci_pad,
+        "epilogue": n_m * bnb * wo * n_j * bco * (3 + (out_bits or 0)),
+    }
 
 
 def conv_kernel_cost(n: int, h: int, w: int, ci: int, co: int, *,
                      fh: int, fw: int, stride: int, padding: int,
                      a_bits: int, w_bits: int, nd_a: int, nd_w: int,
                      bnb: int, bco: int, cache_weights: bool,
-                     cache_acts: bool, out_bits: Optional[int] = None,
+                     out_bits: Optional[int] = None,
                      tpu: TPUConfig = TPUConfig()) -> float:
     """Modeled seconds per implicit-GEMM conv call — roofline over HBM +
-    MXU plus a VPU term for digit-plane assembly.
+    MXU plus a VPU term for digit-plane assembly (:func:`conv_vpu_ops`).
 
-    The hoisting shows up exactly as in :func:`kernel_cost`: cached
-    weight-tap planes are assembled once per (Co-block, f_h) instead of
-    once per grid step; cached activation taps once per (output-row, f_h)
-    instead of once per Co-block visit.
+    Cached weight-tap planes are assembled once per (Co-block, f_h)
+    instead of once per grid step. Activation digits are assembled once
+    per input row of each (image block, Co-block) by the kernel's row
+    ring and every tap window that reads the row slices them, so the
+    unpack is charged per assembled column (:func:`conv_act_columns`).
     """
     ho, wo, hp, wp, ciw, n_nb, n_j = _conv_geom(
         n, h, w, ci, fh, fw, stride, padding, bnb, bco, co)
     ci_pad = ciw * 32
-    n_m = n_nb * ho
-    steps = n_j * n_m * fh
+    steps = n_j * n_nb * ho * fh
+    rows = n_j * n_nb * _ring_rows(ho, fh, stride)
 
-    # HBM: BlockSpec re-fetches a tile each grid step it is mapped
-    act_bytes = steps * a_bits * bnb * wp * ciw * 4
+    # HBM: a packed input row is fetched by the step that assembles it,
+    # a weight tap each grid step it is mapped
+    act_bytes = rows * a_bits * bnb * wp * ciw * 4
     w_bytes = steps * w_bits * fw * ciw * bco * 4
     out_bytes = (out_bits * n_nb * bnb * ho * wo * (n_j * bco // 32) * 4
                  if out_bits else n_nb * bnb * ho * wo * n_j * bco * 4)
     hbm = act_bytes + w_bytes + out_bytes
 
     macs = float(nd_a * nd_w) * steps * fw * (bnb * wo) * ci_pad * bco
-
-    # digit-plane assembly (unpack shifts + int8 scale-adds), VPU-bound
-    tap_work = (w_bits + nd_w) * fw * ci_pad * bco
-    row_work = (a_bits + nd_a) * fw * bnb * wo * ci_pad
-    w_asm = tap_work * n_j * fh * (1 if cache_weights else n_m)
-    a_asm = row_work * n_m * fh * (1 if cache_acts else n_j)
-    epilogue = n_m * bnb * wo * n_j * bco * (3 + (out_bits or 0))
-    vpu = w_asm + a_asm + epilogue
+    vpu = sum(conv_vpu_ops(
+        n, h, w, ci, co, fh=fh, fw=fw, stride=stride, padding=padding,
+        a_bits=a_bits, w_bits=w_bits, nd_a=nd_a, nd_w=nd_w, bnb=bnb,
+        bco=bco, cache_weights=cache_weights, out_bits=out_bits).values())
 
     return max(hbm / tpu.hbm_bw, macs / tpu.int8_macs) + vpu / tpu.vpu_ops
 

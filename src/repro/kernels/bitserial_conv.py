@@ -17,14 +17,19 @@ VMEM:
   one output row × one filter-row tap. The k-step (``f_h``) selects the
   input row ``ih = oh·stride + f_h`` directly in the BlockSpec index map
   (the AGU's row walk); the ``f_w`` taps are walked *inside* the kernel by
-  static strided loads of the packed row held in VMEM (the AGU's column
-  walk) — patch generation is free address arithmetic, exactly like the
-  hardware.
+  static strided loads (the AGU's column walk) — patch generation is free
+  address arithmetic, exactly like the hardware.
 * Digit planes are assembled by ``digits_from_planes`` (int32 shifts and
-  adds, int8 only for the MXU operands) and cached in VMEM scratch
-  mirroring the v2 matmul kernel: weight-tap digits once per (Co-block,
-  f_h) — reused by every output row — and the activation tap digits of
-  each (image block, output row, f_h) once — reused by every Co-block.
+  adds) once per input row, not once per tap: a VMEM ring of FH row slots
+  holds the int32 activation digits of the ``bnb`` images' padded rows,
+  row ``ih`` in slot ``ih % FH``. A row is assembled on the step that
+  first reads it — every ``f_h`` of an image block's first output row,
+  then only the ``min(stride, FH)`` rows each later output row adds — and
+  each of the FH×FW taps that reads it takes its window as a strided
+  slice of the slot, cast to int8 for the MXU. At 3×3 stride 1 on a 32×32
+  map that unpacks each digit once for about 8 taps
+  (:func:`repro.core.cost_model.conv_act_columns`). Weight-tap digits are
+  cached once per (Co-block, f_h) and reused by every output row.
   ``radix_bits=1`` reproduces Algorithm 1 literally; ``radix_bits=7/8`` is
   the MXU-native digit-serial variant (radix chosen by ``plan_spec``).
 * The epilogue fuses the MVU post-pipeline on the last tap: per-output
@@ -34,8 +39,9 @@ VMEM:
   conv layer consumes directly, so ResNet stages chain packed with no
   host-format hop.
 
-Block sizes ``(block_co, block_nb)`` + cache flags come from the conv cost
-model (:func:`repro.kernels.tuning.choose_conv_tile`) unless given.
+Block sizes ``(block_co, block_nb)`` + the weight-cache flag come from the
+conv cost model (:func:`repro.kernels.tuning.choose_conv_tile`) unless
+given.
 """
 
 from __future__ import annotations
@@ -71,32 +77,68 @@ def _assemble_tap_digits(w_ref, spec: SerialSpec, fw: int):
 
 def _assemble_row_digits(x_ref, spec: SerialSpec, *, nb: int, fw: int,
                          wo: int, stride: int):
-    """The FW tap windows of one input row block -> (FW, nd_a, nb*wo,
-    32G) int8 digits. Tap ``f_w`` takes columns ``f_w + stride*o``: a
-    strided load of the packed words (the AGU's column walk), then the
-    images of the block stacked along the GEMM's M axis."""
-    taps = []
-    for i_fw in range(fw):
-        cols = pl.ds(i_fw, wo, stride)
-        words = jnp.concatenate(
-            [x_ref[:, b, 0, cols, :] for b in range(nb)], axis=1)
-        taps.append(digits_from_planes(_Planes(words, 1), spec.a_bits,
-                                       spec.radix_bits, spec.a_signed))
-    return jnp.stack(taps)
+    """One packed input row block (ba, nb, 1, wp, G) uint32 -> (nd_a,
+    nb*cols, 32G) int32 digits, the images stacked along rows: the whole
+    row where the FW tap windows overlap (``cols = wp``), else the
+    windows' own strided columns side by side (``cols = fw*wo``, a 1x1
+    or sparse strided filter), so only columns some tap reads are
+    unpacked. int32, so that every tap window is a 32-bit strided load."""
+    if fw >= stride:
+        parts = [x_ref[:, b, 0] for b in range(nb)]
+    else:
+        parts = [x_ref[:, b, 0, pl.ds(i_fw, wo, stride), :]
+                 for b in range(nb) for i_fw in range(fw)]
+    words = jnp.concatenate(parts, axis=1)
+    return digits_from_planes(_Planes(words, 1), spec.a_bits,
+                              spec.radix_bits, spec.a_signed,
+                              dtype=jnp.int32)
+
+
+def _tap_window(ring, slot, *, i_fw: int, nb: int, fw: int, wo: int,
+                stride: int, cols: int):
+    """Tap ``f_w``'s window of the row in ring ``slot`` -> (nd_a, nb*wo,
+    32G) int8 MXU operand: per image, columns ``f_w + stride*o`` of the
+    assembled row (the AGU's column walk), the images stacked along M and
+    the lane slabs side by side."""
+    if fw >= stride:
+        start, step = i_fw, stride
+    else:
+        start, step = i_fw * wo, 1
+    slabs = [jnp.concatenate(
+        [ring[pl.ds(slot, 1), :, t, pl.ds(b * cols + start, wo, step)][0]
+         for b in range(nb)], axis=1) for t in range(ring.shape[2])]
+    return jnp.concatenate(slabs, axis=-1).astype(jnp.int8)
+
+
+def _store_row(ring, slot, digits):
+    """(nd_a, rows, 32G) int32 digits -> ring ``slot``, one lane slab at a
+    time."""
+    lanes = ring.shape[-1]
+    for t in range(ring.shape[2]):
+        ring[pl.ds(slot, 1), :, t] = jax.lax.slice_in_dim(
+            digits, t * lanes, (t + 1) * lanes, axis=2)[None]
+
+
+def _row_fetched(oh, kk, *, fh: int, stride: int):
+    """The input row whose packed words step (oh, f_h = kk) brings into
+    VMEM: the row the step reads when it assembles it, else the next row
+    the output row will assemble. Consecutive steps that assemble nothing
+    thus name the same block, and Pallas skips their copies, so each row
+    is fetched once per image block like it is assembled once."""
+    if stride >= fh:
+        return oh * stride + kk
+    return jnp.where(oh == 0, kk,
+                     oh * stride + jnp.maximum(kk, fh - stride))
 
 
 def _kernel(x_ref, w_ref, scale_ref, bias_ref, rs_ref, out_ref, acc_ref,
-            *scratch, spec: SerialSpec, fh: int, fw: int, stride: int,
-            wo: int, nb: int, relu: bool, out_dtype,
+            ring, *scratch, spec: SerialSpec, fh: int, fw: int, stride: int,
+            ho: int, wo: int, nb: int, cols: int, relu: bool, out_dtype,
             requant: Optional[QuantSpec], emit_packed: bool,
-            cache_weights: bool, cache_acts: bool):
-    j = pl.program_id(0)    # Co-block (outermost)
+            cache_weights: bool):
     m = pl.program_id(1)    # (image-block, output-row) pair
     kk = pl.program_id(2)   # filter-row tap f_h (innermost reduction)
-
-    scr = list(scratch)
-    w_scr = scr.pop(0) if cache_weights else None
-    a_scr = scr.pop(0) if cache_acts else None
+    w_scr = scratch[0] if cache_weights else None
 
     @pl.when(kk == 0)
     def _init():
@@ -113,24 +155,30 @@ def _kernel(x_ref, w_ref, scale_ref, bias_ref, rs_ref, out_ref, acc_ref,
     else:
         wd = _assemble_tap_digits(w_ref, spec, fw)
 
-    # --- activation tap digit planes of (image block, output row, f_h):
-    # assembled while j == 0 and reused by every later Co-block ----------
-    row = functools.partial(_assemble_row_digits, x_ref, spec, nb=nb,
+    # --- activation digits: the row ring. Input row ih = oh*stride + f_h
+    # lives in slot ih % fh; it is assembled on the step that first reads
+    # it (every f_h of an image block's first output row, then the
+    # min(stride, fh) rows each later output row adds) and every later tap
+    # that reads it slices its window from the slot. A new image block or
+    # Co-block restarts at oh == 0, which refills the ring. -------------
+    oh = m % ho
+    slot = (oh * stride + kk) % fh
+    asm = functools.partial(_assemble_row_digits, x_ref, spec, nb=nb,
                             fw=fw, wo=wo, stride=stride)
-    if cache_acts:
-        slot = m * fh + kk
 
-        @pl.when(j == 0)
-        def _fill_a():
-            a_scr[pl.ds(slot, 1)] = row()[None]
-        xd = a_scr[pl.ds(slot, 1)][0]
+    def _fill_row():
+        _store_row(ring, slot, asm())
+    if stride >= fh:
+        _fill_row()
     else:
-        xd = row()
+        pl.when((oh == 0) | (kk >= fh - stride))(_fill_row)
 
     # --- the f_w taps: one digit-plane GEMM each ------------------------
     tile = None
     for i_fw in range(fw):
-        p = _digit_matmul_acc(xd[i_fw], wd[:, i_fw], spec.radix_bits)
+        xd = _tap_window(ring, slot, i_fw=i_fw, nb=nb, fw=fw, wo=wo,
+                         stride=stride, cols=cols)
+        p = _digit_matmul_acc(xd, wd[:, i_fw], spec.radix_bits)
         tile = p if tile is None else tile + p
     acc_ref[...] += tile
 
@@ -173,7 +221,6 @@ def bitserial_conv2d_v2_pallas(
     requant_scale: Optional[jax.Array] = None,
     emit_packed: bool = False,
     cache_weights: bool = True,
-    cache_acts: bool = True,
     tpu=None,
     interpret: bool = False,
 ) -> jax.Array:
@@ -242,27 +289,24 @@ def bitserial_conv2d_v2_pallas(
     # VMEM safety net for explicit-block callers, using the SAME estimate
     # and budget as the tuner (a tuner-approved config therefore always
     # passes unmodified — pass the tuner's ``tpu`` when using a non-default
-    # part): drop caches, activations first, until the working set fits.
-    from repro.core.cost_model import conv_kernel_vmem_bytes, vmem_budget_bytes
-    budget = vmem_budget_bytes(tpu)
-
-    def _vmem(cw, ca):
-        return conv_kernel_vmem_bytes(
-            n, h, w_in, ci, co, fh=fh, fw=fw, stride=stride, padding=padding,
-            a_bits=spec.a_bits, w_bits=spec.w_bits, nd_a=nd_a, nd_w=nd_w,
-            bnb=nb, bco=block_co, cache_weights=cw, cache_acts=ca,
-            out_bits=requant.bits if (requant and emit_packed) else None)
-    if cache_acts and _vmem(cache_weights, True) > budget:
-        cache_acts = False
-    if cache_weights and _vmem(True, cache_acts) > budget:
+    # part): drop the weight cache if the working set does not fit.
+    from repro.core.cost_model import (conv_kernel_vmem_bytes, conv_ring_lanes,
+                                       conv_row_cols, vmem_budget_bytes)
+    if cache_weights and conv_kernel_vmem_bytes(
+            n, h, w_in, ci, co, fh=fh, fw=fw, stride=stride,
+            padding=padding, a_bits=spec.a_bits, w_bits=spec.w_bits,
+            nd_a=nd_a, nd_w=nd_w, bnb=nb, bco=block_co, cache_weights=True,
+            out_bits=requant.bits if (requant and emit_packed) else None
+    ) > vmem_budget_bytes(tpu):
         cache_weights = False
 
-    scratch = [pltpu.VMEM((nb * wo, block_co), jnp.int32)]
+    cols = conv_row_cols(fw, wo, stride, wp)
+    lanes = conv_ring_lanes(ci_pad)
+    scratch = [pltpu.VMEM((nb * wo, block_co), jnp.int32),
+               pltpu.VMEM((fh, nd_a, ci_pad // lanes, nb * cols, lanes),
+                          jnp.int32)]
     if cache_weights:
         scratch.append(pltpu.VMEM((fh, nd_w, fw, ci_pad, block_co), jnp.int8))
-    if cache_acts:
-        scratch.append(pltpu.VMEM((n_nb * ho * fh, fw, nd_a, nb * wo,
-                                   ci_pad), jnp.int8))
 
     if emit_packed:
         out_shape = jax.ShapeDtypeStruct(
@@ -279,20 +323,20 @@ def bitserial_conv2d_v2_pallas(
                                 lambda j, m, kk: (j, m // ho, m % ho, 0, 0))
 
     kernel = functools.partial(
-        _kernel, spec=spec, fh=fh, fw=fw, stride=stride, wo=wo, nb=nb,
-        relu=relu, out_dtype=out_dtype, requant=requant,
-        emit_packed=emit_packed, cache_weights=cache_weights,
-        cache_acts=cache_acts)
+        _kernel, spec=spec, fh=fh, fw=fw, stride=stride, ho=ho, wo=wo,
+        nb=nb, cols=cols, relu=relu, out_dtype=out_dtype, requant=requant,
+        emit_packed=emit_packed, cache_weights=cache_weights)
 
     out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             # input row ih = oh*stride + f_h: the AGU row walk lives in the
-            # index map (H block size 1 => block index == element row)
+            # index map (H block size 1 => block index == element row),
+            # fetched only by the steps that assemble it
             pl.BlockSpec((ba, nb, 1, wp, ciw),
-                         lambda j, m, kk: (0, m // ho,
-                                           (m % ho) * stride + kk, 0, 0)),
+                         lambda j, m, kk: (0, m // ho, _row_fetched(
+                             m % ho, kk, fh=fh, stride=stride), 0, 0)),
             pl.BlockSpec((1, bw, 1, fw, ciw, block_co),
                          lambda j, m, kk: (j, 0, kk, 0, 0, 0)),
             pl.BlockSpec((1, 1, block_co), lambda j, m, kk: (j, 0, 0)),
@@ -302,8 +346,9 @@ def bitserial_conv2d_v2_pallas(
         out_specs=out_spec,
         out_shape=out_shape,
         scratch_shapes=scratch,
-        # scratch reuse spans grid steps along every dimension, so all three
-        # must stay sequential on one core ("arbitrary", not "parallel")
+        # the row ring and the weight cache carry over grid steps, so all
+        # three axes stay sequential on one core ("arbitrary", not
+        # "parallel")
         compiler_params=_compiler_params("arbitrary", "arbitrary",
                                          "arbitrary"),
         interpret=interpret,

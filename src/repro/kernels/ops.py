@@ -166,7 +166,6 @@ def serial_conv2d_packed_op(
     block_co: Optional[int] = None,
     block_nb: Optional[int] = None,
     cache_weights: Optional[bool] = None,
-    cache_acts: Optional[bool] = None,
 ) -> jax.Array:
     """Fused implicit-GEMM serial conv2d over **bit-packed activations**.
 
@@ -191,11 +190,9 @@ def serial_conv2d_packed_op(
             tile_kwargs = dict(block_co=block_co, block_nb=block_nb)
             if cache_weights is not None:
                 tile_kwargs["cache_weights"] = cache_weights
-            if cache_acts is not None:
-                tile_kwargs["cache_acts"] = cache_acts
         else:
             # pinned axes constrain the tuner; the rest (other axis + cache
-            # flags) is still tuned and VMEM-validated jointly
+            # flag) is still tuned and VMEM-validated jointly
             tc = tuning.choose_conv_tile(
                 n, h, w_in, ci, co, fh=fh, fw=fw, stride=stride,
                 padding=padding, spec=spec,

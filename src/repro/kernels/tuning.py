@@ -32,7 +32,8 @@ from typing import Optional, Tuple
 
 from repro.core import bitops, cost_model
 from repro.core.bitserial import SerialSpec
-from repro.core.cost_model import (TPUConfig, conv_kernel_cost,
+from repro.core.cost_model import (TPUConfig, conv_act_columns,
+                                   conv_kernel_cost,
                                    conv_kernel_vmem_bytes, kernel_cost,
                                    kernel_vmem_bytes)
 
@@ -307,19 +308,26 @@ def choose_tile_measured(m: int, k: int, n: int, spec: SerialSpec, *,
 @dataclasses.dataclass(frozen=True)
 class ConvTileConfig:
     """One tuned implicit-GEMM conv configuration (kwargs for the Pallas
-    call): Co-block width, images per grid step, cache flags."""
+    call): Co-block width, images per grid step, weight-cache flag; with
+    the activation digit columns one call serves to its tap windows and
+    assembles in its row ring (``cost_model.conv_act_columns``)."""
 
     block_co: int
     block_nb: int
     cache_weights: bool
-    cache_acts: bool
     cost: float = 0.0          # modeled seconds/call (diagnostic)
     vmem_bytes: int = 0        # modeled VMEM working set (diagnostic)
+    act_cols_served: int = 0     # per call (static grid arithmetic)
+    act_cols_assembled: int = 0  # per call
+
+    @property
+    def act_digit_reuse(self) -> float:
+        """Tap-window digit columns served per column assembled."""
+        return self.act_cols_served / max(1, self.act_cols_assembled)
 
     def kernel_kwargs(self) -> dict:
         return dict(block_co=self.block_co, block_nb=self.block_nb,
-                    cache_weights=self.cache_weights,
-                    cache_acts=self.cache_acts)
+                    cache_weights=self.cache_weights)
 
 
 _BCO_CANDIDATES = (32, 64, 128, 256, 512)    # %32: packed-output word axis
@@ -330,8 +338,8 @@ def _enumerate_conv_tiles(n, h, w, ci, co, *, fh, fw, stride, padding,
                           spec, out_bits, fix_bco, fix_bnb, tpu):
     """All VMEM-feasible conv tile configs that meet the tiling rule,
     best-first (modeled cost ascending, larger Co-block × image group
-    breaking ties). A digit-plane cache is only considered where a later
-    grid step reuses it."""
+    breaking ties). The weight digit cache is only considered where a
+    later grid step reuses it."""
     nd_a = bitops.num_digits(spec.a_bits, spec.radix_bits, spec.a_signed)
     nd_w = bitops.num_digits(spec.w_bits, spec.radix_bits, spec.w_signed)
     budget = cost_model.vmem_budget_bytes(tpu)
@@ -341,26 +349,27 @@ def _enumerate_conv_tiles(n, h, w, ci, co, *, fh, fw, stride, padding,
     bnb_opts = ([fix_bnb] if fix_bnb is not None
                 else [b for b in _BNB_CANDIDATES if b <= max(1, n)])
     ho = (h + 2 * padding - fh) // stride + 1
+    geom = dict(fh=fh, fw=fw, stride=stride, padding=padding)
     cands = []
     for bco in bco_opts:
         if not conv_tile_ok(bco):
             continue
         for bnb in bnb_opts:
-            n_j, n_m = -(-co // bco), -(-n // bnb) * ho
-            for cw, ca in ((True, True), (True, False),
-                           (False, True), (False, False)):
-                if (cw and n_m == 1) or (ca and n_j == 1):
+            n_m = -(-n // bnb) * ho
+            for cw in (True, False):
+                if cw and n_m == 1:
                     continue
-                kw = dict(fh=fh, fw=fw, stride=stride, padding=padding,
-                          a_bits=spec.a_bits, w_bits=spec.w_bits,
+                kw = dict(geom, a_bits=spec.a_bits, w_bits=spec.w_bits,
                           nd_a=nd_a, nd_w=nd_w, bnb=bnb, bco=bco,
-                          cache_weights=cw, cache_acts=ca,
-                          out_bits=out_bits)
+                          cache_weights=cw, out_bits=out_bits)
                 vmem = conv_kernel_vmem_bytes(n, h, w, ci, co, **kw)
                 if vmem > budget:
                     continue
                 cost = conv_kernel_cost(n, h, w, ci, co, **kw, tpu=tpu)
-                cands.append(ConvTileConfig(bco, bnb, cw, ca, cost, vmem))
+                cols = conv_act_columns(n, h, w, ci, co, **geom, bnb=bnb,
+                                        bco=bco)
+                cands.append(ConvTileConfig(bco, bnb, cw, cost, vmem,
+                                            *cols))
     cands.sort(key=lambda c: (c.cost, -(c.block_co * c.block_nb)))
     return cands
 
@@ -371,14 +380,15 @@ def choose_conv_tile(n: int, h: int, w: int, ci: int, co: int, *,
                      fix_bco: Optional[int] = None,
                      fix_bnb: Optional[int] = None,
                      tpu: TPUConfig = TPUConfig()) -> ConvTileConfig:
-    """Pick (block_co, block_nb, cache flags) for one conv shape.
+    """Pick (block_co, block_nb, weight-cache flag) for one conv shape.
 
     The spatial/M blocking is fixed by the kernel's AGU walk (one output
     row × ``block_nb`` images per grid step; K-blocking = the FH grid axis
     + in-kernel FW walk), so the tuner's degrees of freedom are the
-    Co-block width, the image grouping, and whether the digit-plane caches
-    fit VMEM. ``fix_bco``/``fix_bnb`` pin one axis (caller override) while
-    the rest is still tuned and VMEM-validated jointly. Raises
+    Co-block width, the image grouping (which also sizes the activation
+    row ring), and whether the weight digit cache fits VMEM.
+    ``fix_bco``/``fix_bnb`` pin one axis (caller override) while the rest
+    is still tuned and VMEM-validated jointly. Raises
     ``ValueError`` when no tile meets the tiling rule within the VMEM
     budget. Memoized per (shape, spec, out_bits, pins, tpu).
     """

@@ -138,6 +138,10 @@ class ModelRegistry:
         self._c_art_spills = m.counter(
             "registry_artifact_spills_total",
             "evictions that left a disk reference")
+        self._g_act_reuse = m.gauge(
+            "conv_act_digit_reuse",
+            "conv kernel: tap-window activation digit columns served per "
+            "column its row ring assembles, per variant and layer")
 
     # legacy attribute surface, now registry-backed
     @property
@@ -226,6 +230,7 @@ class ModelRegistry:
         with self._lock:
             self._check_new(key)
             self._share_packed(program)
+            self._note_built(key, program)
             self._entries[key] = _Entry("program", program=program)
         return key
 
@@ -275,6 +280,7 @@ class ModelRegistry:
                 return prog
             prog = self._materialize(key, e)
             self._share_packed(prog)
+            self._note_built(key, prog)
             self._lru[key] = prog
             while (self.max_programs is not None
                    and len(self._lru) > self.max_programs):
@@ -284,6 +290,14 @@ class ModelRegistry:
                 if oe is not None and oe.ref is not None:
                     self._c_art_spills.inc()
             return prog
+
+    def _note_built(self, key: ModelKey, program) -> None:
+        """Export the static per-layer conv counters of a Program's tuned
+        tiles (``meta["tiles"]``) as gauges."""
+        for layer, tile in program.meta.get("tiles", {}).items():
+            if getattr(tile, "act_cols_assembled", 0):
+                self._g_act_reuse.set(tile.act_digit_reuse,
+                                      variant=str(key), layer=layer)
 
     def _materialize(self, key: ModelKey, e: _Entry):
         """Load from the store if possible, else compile (and save)."""

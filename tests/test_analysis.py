@@ -225,8 +225,7 @@ def test_defect_tile_over_vmem_budget(tiny_prog):
                        if s.kind == "conv_packed")
     attrs = dict(victim.attrs)
     tile = dict(attrs["tile"])
-    tile.update(block_nb=1 << 16, block_co=1 << 16,
-                cache_weights=True, cache_acts=True)
+    tile.update(block_nb=1 << 16, block_co=1 << 16, cache_weights=True)
     attrs["tile"] = tile
     steps[idx] = dataclasses.replace(victim, attrs=attrs)
     with pytest.raises(VerifyError) as ei:

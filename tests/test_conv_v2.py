@@ -225,6 +225,132 @@ def test_conv_v2_layer_chaining_no_host_hop():
                                   _dense_ref(h_codes, w2, 1, 1))
 
 
+# ------------------------------------------------------------- the row ring
+
+# (a_bits, w_bits, signed, radix, n, h, w, ci, co, fs, stride, padding,
+#  block_co, block_nb, out): every case pins block_co below co (so the
+# ring refills for each of several Co-blocks) and an image count that
+# block_nb does not divide
+RING_CASES = {
+    "s1p1_float": (4, 4, True, 8, 3, 7, 9, 33, 64, 3, 1, 1, 32, 2, "float"),
+    "s2p1_codes": (4, 4, True, 8, 3, 7, 9, 33, 64, 3, 2, 1, 32, 2, "codes"),
+    "s1p0_packed": (2, 2, True, 7, 3, 6, 8, 40, 64, 3, 1, 0, 32, 2,
+                    "packed"),
+    "s2p0_float": (8, 4, True, 8, 5, 9, 7, 32, 64, 3, 2, 0, 32, 4, "float"),
+    "fh1_s1_codes": (4, 4, True, 8, 3, 5, 6, 33, 64, 1, 1, 0, 32, 2,
+                     "codes"),
+    "fh1_s2_packed": (4, 4, True, 8, 3, 6, 6, 33, 64, 1, 2, 0, 32, 2,
+                      "packed"),
+    "radix1_s1_float": (3, 2, False, 1, 3, 5, 5, 32, 64, 3, 1, 1, 32, 2,
+                        "float"),
+    "radix1_s2_packed": (2, 3, True, 1, 3, 7, 7, 33, 64, 3, 2, 1, 32, 2,
+                         "packed"),
+    "wide_ci_s2_codes": (2, 2, True, 7, 3, 5, 5, 300, 64, 3, 2, 1, 32, 2,
+                         "codes"),
+}
+
+
+@pytest.mark.parametrize("case", list(RING_CASES))
+def test_conv_v2_row_ring_bit_exact(case):
+    """Each input row's digits are assembled once into the ring and every
+    tap window is sliced from it: bit-exact with the packed implicit-GEMM
+    oracle for every stride, padding, filter height, ragged image block,
+    Co-block count, multi-digit radix-1 activation (nd_a > 1), channel
+    width over one lane slab, and output format."""
+    (ba, bw, signed, radix, n, h, w, ci, co, fs, stride, padding, bco, bnb,
+     out_kind) = RING_CASES[case]
+    rng = np.random.RandomState(sum(map(ord, case)))
+    x, wt = _rand_case(rng, ba, bw, signed, signed, n, h, w, ci, co, fs=fs)
+    spec = SerialSpec(ba, bw, signed, signed, radix)
+    if radix == 1:
+        assert bitops.num_digits(ba, radix, signed) > 1
+    xp, wp = pack_activations(jnp.asarray(x), ba), _pack_w(wt, bw)
+    acc = np.asarray(serial_conv2d_packed_acts(
+        xp, wp, spec=spec, ci=ci, stride=stride, padding=padding))
+    np.testing.assert_array_equal(acc, _dense_ref(x, wt, stride, padding))
+    if out_kind == "float":
+        out = bitserial_conv2d_v2_pallas(
+            xp, wp, np.ones(co, np.float32), None, spec=spec, ci=ci,
+            stride=stride, padding=padding, block_co=bco, block_nb=bnb,
+            interpret=True)
+        np.testing.assert_array_equal(np.asarray(out).astype(np.int64), acc)
+        return
+    scale = (rng.rand(co) * 0.05).astype(np.float32)
+    kw = dict(spec=spec, ci=ci, stride=stride, padding=padding, relu=True,
+              requant=QuantSpec(ba, signed), requant_scale=0.5,
+              emit_packed=out_kind == "packed", block_co=bco, block_nb=bnb,
+              interpret=True)
+    got = serial_conv2d_packed_op(xp, wp, scale, None, backend="pallas_v2",
+                                  **kw)
+    want = serial_conv2d_packed_op(xp, wp, scale, None, backend="xla", **kw)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_conv_act_columns_reuse_at_resnet9_geometry():
+    """conv1/conv2 (32x32, 3x3, stride 1) serve each assembled digit
+    column to about 8 tap windows, conv3 (stride 2) to about 2; a 1x1
+    stride-1 conv has nothing to share."""
+    from repro.core.cost_model import conv_act_columns
+    geo = dict(fh=3, fw=3, padding=1, bnb=8)
+    s, a = conv_act_columns(256, 32, 32, 64, 64, stride=1, bco=64, **geo)
+    assert s / a == pytest.approx(9 * 32 * 32 / 34 ** 2)
+    assert round(s / a, 2) == 7.97
+    s, a = conv_act_columns(256, 32, 32, 64, 128, stride=2, bco=128, **geo)
+    assert round(s / a, 2) == 2.05
+    s, a = conv_act_columns(256, 16, 16, 64, 64, fh=1, fw=1, stride=1,
+                            padding=0, bnb=8, bco=64)
+    assert s == a
+
+
+def test_conv_cost_charges_act_assembly_per_assembled_row():
+    """The activation unpack term of conv1's geometry is the per-tap-window
+    charge divided by the reuse factor; the ring is in the VMEM estimate
+    and scales with the image block."""
+    from repro.core import cost_model
+    spec = plan_spec(SerialSpec(8, 4, True, True, 7))
+    nd_a = bitops.num_digits(8, spec.radix_bits, True)
+    nd_w = bitops.num_digits(4, spec.radix_bits, True)
+    geo = dict(fh=3, fw=3, stride=1, padding=1, a_bits=8, w_bits=4,
+               nd_a=nd_a, nd_w=nd_w, bco=64, cache_weights=True, out_bits=8)
+    ops = cost_model.conv_vpu_ops(256, 32, 32, 64, 64, bnb=8, **geo)
+    # what unpacking every tap window on its own costs: (a_bits + nd_a)
+    # element ops per channel lane of each window column
+    per_window = (8 + nd_a) * 256 * 32 * 9 * 32 * 64
+    assert per_window / ops["act_rows"] == pytest.approx(
+        9 * 32 * 32 / 34 ** 2)
+    assert ops["act_windows"] == nd_a * 256 * 32 * 9 * 32 * 64
+    v = {b: cost_model.conv_kernel_vmem_bytes(256, 32, 32, 64, 64, bnb=b,
+                                              **geo) for b in (4, 8)}
+    ring8 = 3 * nd_a * 8 * 34 * 128 * 4        # (fh*nd_a, 272 rows, 128)
+    assert v[8] - v[4] >= ring8 // 2
+    assert v[8] > ring8
+
+
+def test_conv_act_digit_reuse_gauge():
+    """Building a Program exports each conv layer's reuse as
+    ``conv_act_digit_reuse`` in the registry's metrics: about 7.97 for a
+    32x32 stride-1 layer, 2.05 for a stride-2 one."""
+    from repro.models.layers import QuantPolicy
+    from repro.models.resnet import (ResNet9Config, resnet9_graph,
+                                     resnet9_init)
+    from repro.serving import ModelRegistry
+
+    class Cfg(ResNet9Config):
+        layers = (("conv1", 64, 64, 1, False), ("conv3", 64, 128, 2, False))
+
+    cfg = Cfg()
+    graph = resnet9_graph(resnet9_init(jax.random.PRNGKey(0), cfg), cfg)
+    calib = np.random.RandomState(0).rand(8, 32, 32, 3).astype(np.float32)
+    reg = ModelRegistry(backend="xla")
+    key = reg.register_graph("cnn", graph, calib, QuantPolicy(
+        mode="serial", w_bits=2, a_bits=2, radix_bits=7))
+    prog = reg.program(key)
+    g = reg.metrics_registry.get("conv_act_digit_reuse")
+    assert prog.meta["tiles"]["conv1"].block_nb == 8
+    assert round(g.value(variant=str(key), layer="conv1"), 2) == 7.97
+    assert round(g.value(variant=str(key), layer="conv3"), 2) == 2.05
+
+
 # ----------------------------------------------------------------- autotuner
 
 def test_conv_tuner_respects_vmem_and_caches():
@@ -233,10 +359,14 @@ def test_conv_tuner_respects_vmem_and_caches():
                                  padding=1, spec=spec)
     assert tc.block_co % 32 == 0 and tc.block_nb >= 1
     assert tc.vmem_bytes <= int(tuning.TPUConfig().vmem_bytes * 0.75)
-    # huge activation grid: the full row-digit cache cannot fit -> disabled
+    # huge activation rows: the row ring scales with the image block, so
+    # the tuner shrinks the block until the ring fits
     tc_big = tuning.choose_conv_tile(64, 224, 224, 512, 512, fh=3, fw=3,
                                      stride=1, padding=1, spec=spec)
-    assert not tc_big.cache_acts
+    ring8 = 3 * 8 * 226 * 512 * 4          # fh slots x 8 images' rows, int32
+    assert ring8 > int(tuning.TPUConfig().vmem_bytes * 0.75) * 0.8
+    assert tc_big.block_nb < 8
+    assert tc_big.vmem_bytes >= 3 * tc_big.block_nb * 226 * 512 * 4
     assert tc_big.vmem_bytes <= int(tuning.TPUConfig().vmem_bytes * 0.75)
 
 

@@ -79,6 +79,31 @@ def test_conv_v2_compiles(one_chip, layer):
         assert "tpu_custom_call" in txt, (layer, w, a)
 
 
+@pytest.mark.parametrize("w,a", [(4, 8), (2, 2)], ids=["W4A8", "W2A2"])
+def test_conv1_compiles_at_served_batch(one_chip, w, a):
+    """conv1 at the served batch of 256 with the tuner's tile and the
+    packed requant epilogue: Mosaic's tiling and VMEM checks see the row
+    ring at the size the benchmark runs."""
+    from repro.kernels import tuning
+    from repro.kernels.ops import serial_conv2d_packed_op
+    n, h, ci, co = 256, 32, 64, 64
+    spec = _spec(w, a)
+    tc = tuning.choose_conv_tile(n, h, h, ci, co, fh=3, fw=3, stride=1,
+                                 padding=1, spec=spec, out_bits=a)
+    assert tc.block_nb == 8
+
+    def conv(x, wp, scale, bias, rs):
+        return serial_conv2d_packed_op(
+            x, wp, scale, bias, spec=spec, ci=ci, stride=1, padding=1,
+            relu=True, requant=QuantSpec(a, True), requant_scale=rs,
+            emit_packed=True, backend="pallas_v2", **tc.kernel_kwargs())
+    txt = _compile_text(
+        conv, one_chip, ((a, n, h, h, ci // 32), jnp.uint32),
+        ((w, 3, 3, ci // 32, co), jnp.uint32), ((co,), jnp.float32),
+        ((co,), jnp.float32), ((), jnp.float32))
+    assert "tpu_custom_call" in txt
+
+
 def test_matmul_v2_compiles_at_lm_width(one_chip):
     """The stablelm-1.6b MLP up-projection, W4A8, 256 rows."""
     from repro.kernels.ops import serial_matmul_packed_op
