@@ -8,8 +8,10 @@ For each seed, in one process: the cell's set-up and a short window of its
 own traffic, then the answers sampled as a run samples them, compared with
 the reference (the program's reading), and the control's logits for the
 same images compared with the reference (the control's reading). The
-control is the reference with its float steps in bfloat16, one step below
-the float32 the configuration states. Benchmark runs never run it.
+reference and the control come from the model module the configuration
+names (``bench/models/<model>.py``); the control is the reference with its
+float steps in bfloat16, one step below the float32 the configuration
+states. Benchmark runs never run it.
 """
 
 from __future__ import annotations
@@ -27,7 +29,7 @@ for _p in (str(ROOT / "src"), str(ROOT)):
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from bench import reference, run_cell, spec  # noqa: E402
+from bench import run_cell, spec  # noqa: E402
 
 
 def readings(plan: dict, seed: int, seconds: float) -> dict:
@@ -40,8 +42,9 @@ def readings(plan: dict, seed: int, seconds: float) -> dict:
     imgs = served.pool[rec.image[picked]]
     params, calib, cfg = served.params, served.calib, plan["config"]
     served.close()
-    ref = reference.logits(params, calib, imgs, cfg)
-    ctl = reference.logits(params, calib, imgs, cfg, dtype=jnp.bfloat16)
+    model = plan["model"]
+    ref = model.logits(params, calib, imgs, cfg)
+    ctl = model.logits(params, calib, imgs, cfg, dtype=jnp.bfloat16)
     return {"seed": seed, "answered": int(len(done)),
             "failed": int(len(failed)), "checked": int(len(picked)),
             "program": run_cell.numbers(got, ref),

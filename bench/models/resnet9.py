@@ -1,8 +1,9 @@
-"""Plain reference of the served quantized ResNet9, and the weights both
-sides use.
+"""ResNet9 as the benchmark serves and checks it: the seeded weights, the
+compiler graph the server takes, the plain reference, and the convs and
+multiply-accumulates of the served graph.
 
-Nothing here imports the system under test. The semantics are BARVINN's
-deployment flow as the configuration file states it:
+The reference imports nothing of the system under test. The semantics are
+BARVINN's deployment flow as the configuration file states it:
 
 * conv0 (3 -> 64, 3x3, pad 1) and the classifier stay float on the host;
 * every hidden conv quantizes its input per tensor and its weights per
@@ -25,9 +26,16 @@ instead. The integer convolutions are exact in both.
 
 from __future__ import annotations
 
+import types
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench import quantref, roofline
+from bench.quantref import key_for
+from bench.quantref import lsq_codes as _codes
+from bench.quantref import lsq_step as _step
 
 HIGHEST = jax.lax.Precision.HIGHEST
 DEFAULT = jax.lax.Precision.DEFAULT
@@ -36,13 +44,6 @@ DEFAULT = jax.lax.Precision.DEFAULT
 def layers(cfg):
     """(name, c_in, c_out, stride, pool) of every hidden conv."""
     return [tuple(l) for l in cfg["layers"]]
-
-
-def key_for(seed: int, stream: int):
-    """A JAX key from a seed of any size, 32 bits or more."""
-    words = np.random.SeedSequence([int(seed), stream]).generate_state(2)
-    return jax.random.fold_in(jax.random.PRNGKey(int(words[0])),
-                              int(words[1]))
 
 
 def make_params(cfg, seed: int):
@@ -73,27 +74,6 @@ def make_params(cfg, seed: int):
         return p
 
     return jax.jit(make)(key_for(seed, 0))
-
-
-def make_images(seed: int, n: int, hw: int, c: int, stream: int) -> np.ndarray:
-    """``n`` CIFAR-shaped images in [0, 1), float32, on the host."""
-    rng = np.random.default_rng([int(seed), stream])
-    return rng.random((n, hw, hw, c), dtype=np.float32)
-
-
-def _qrange(bits: int):
-    return -(1 << (bits - 1)), (1 << (bits - 1)) - 1
-
-
-def _step(x, bits: int, axis=None):
-    _, qp = _qrange(bits)
-    m = jnp.mean(jnp.abs(x), axis=axis, keepdims=axis is not None)
-    return 2.0 * m / np.sqrt(max(qp, 1)) + 1e-8
-
-
-def _codes(x, step, bits: int):
-    qn, qp = _qrange(bits)
-    return jnp.clip(jnp.round(x / step), qn, qp)
 
 
 def _conv(x, w, stride: int, precision=HIGHEST):
@@ -182,22 +162,44 @@ def logits(params, calib_images, images, cfg, *, dtype=jnp.float32,
     control (``dtype=jnp.bfloat16``), computed ``block`` images at a time
     so that the whole set never sits on the device."""
     cal = calibrate(params, calib_images, cfg, dtype)
-    fwd = jax.jit(_forward, static_argnums=(3, 4))
-    out = []
-    for i in range(0, len(images), block):
-        chunk = images[i:i + block]
-        n = len(chunk)
-        if n < block:   # one compiled shape for every block
-            chunk = np.concatenate(
-                [chunk, np.zeros((block - n,) + chunk.shape[1:],
-                                 chunk.dtype)])
-        out.append(np.asarray(fwd(params, cal, jnp.asarray(chunk),
-                                  _Frozen(cfg), dtype))[:n])
-    return np.concatenate(out) if out else np.zeros((0, cfg["num_classes"]))
+    return quantref.blocked(_forward, params, cal, images, cfg, dtype=dtype,
+                            block=block)
 
 
-class _Frozen(dict):
-    """A configuration dict that jit can take as a static argument."""
+def graph(params, cfg):
+    """The compiler graph the server takes, from the program's own ResNet9
+    builder."""
+    from repro.models.resnet import resnet9_graph
+    return resnet9_graph(params, types.SimpleNamespace(
+        layers=layers(cfg), num_classes=int(cfg["num_classes"])),
+        input_hw=int(cfg["input_hw"]))
 
-    def __hash__(self):
-        return hash(repr(sorted((k, repr(v)) for k, v in self.items())))
+
+def convs(cfg: dict) -> list:
+    """Every hidden conv as it is served: 3x3, pad 1, the layer's stride;
+    input and output size, and the format it writes (the next layer's
+    packed planes, int8 codes where a pool follows, float after the
+    last)."""
+    out, h = [], int(cfg["input_hw"])
+    ls = layers(cfg)
+    for i, (name, ci, co, stride, pool) in enumerate(ls):
+        ho = (h + 2 - 3) // stride + 1
+        last = i == len(ls) - 1
+        out.append({"name": name, "h": h, "w": h, "ci": ci, "co": co,
+                    "fh": 3, "fw": 3, "stride": stride, "padding": 1,
+                    "ho": ho, "wo": ho,
+                    "out": "float" if last else ("codes" if pool
+                                                 else "packed")})
+        h = ho // 2 if pool else ho
+    return out
+
+
+def macs_per_image(cfg: dict) -> int:
+    """Multiply-accumulates of one image through the whole served graph:
+    conv0, the hidden convs and the classifier."""
+    hw = int(cfg["input_hw"])
+    conv0 = hw * hw * 9 * int(cfg["input_channels"]) * int(
+        cfg["conv0_channels"])
+    hidden = sum(roofline.conv_macs(l, 1) for l in convs(cfg))
+    fc = int(cfg["layers"][-1][2]) * int(cfg["num_classes"])
+    return conv0 + hidden + fc

@@ -6,11 +6,14 @@
 The cell serves its configuration through the normal path
 (``launch/serve.CNNServer`` -> serving registry, batcher, scheduler,
 service -> ``compiler/executor.BucketedRunner`` -> Pallas kernels) under
-its traffic mix for ``--seconds``. ``--trace 0`` prints the cell's
-end-to-end metrics; ``--trace 1`` records a device trace of part of the
-window and prints its per-layer metrics. Both check the answers the window
-returned against ``bench/reference.py`` and print each compared number
-beside its limit, last on standard error and last in the result line.
+its traffic mix for ``--seconds``. The architecture comes from the model
+module the configuration names (``bench/models/<model>.py``): its seeded
+weights, the graph the server compiles, and the plain reference. ``--trace
+0`` prints the cell's end-to-end metrics; ``--trace 1`` records a device
+trace of part of the window and prints its per-layer metrics. Both check
+the answers the window returned against the model's reference and print
+each compared number beside its limit, last on standard error and last in
+the result line.
 
 Exits non-zero, printing no result, without a TPU, with fewer chips than
 the cell asks for, or on a device kind that ``bench/peaks.json`` lacks.
@@ -38,7 +41,7 @@ for _p in (str(ROOT / "src"), str(ROOT)):
 
 import numpy as np  # noqa: E402
 
-from bench import reference, roofline, spec, trace, traffic  # noqa: E402
+from bench import roofline, spec, trace, traffic  # noqa: E402
 
 #: seconds into the window at which the traced run starts the profiler,
 #: and how long it records
@@ -66,24 +69,23 @@ class Served:
 
     def __init__(self, plan: dict, seed: int):
         import jax
+        from bench import quantref
         from repro.launch.serve import CNNServer
         from repro.models.layers import QuantPolicy
-        from repro.models.resnet import resnet9_graph
         from repro.runtime.compile_cache import enable_compile_cache
 
         cfg = self.cfg = plan["config"]
+        model = plan["model"]
         enable_compile_cache()
         # every program of the run is cached, however short its compile
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        self.params = reference.make_params(cfg, seed)
+        self.params = model.make_params(cfg, seed)
         hw, c = int(cfg["input_hw"]), int(cfg["input_channels"])
-        self.calib = reference.make_images(seed, int(cfg["calib_images"]),
-                                           hw, c, stream=1)
-        self.pool = reference.make_images(seed, int(cfg["image_pool"]), hw,
-                                          c, stream=2)
-        graph = resnet9_graph(self.params, types.SimpleNamespace(
-            layers=[tuple(l) for l in cfg["layers"]],
-            num_classes=int(cfg["num_classes"])), input_hw=hw)
+        self.calib = quantref.make_images(seed, int(cfg["calib_images"]),
+                                          hw, c, stream=1)
+        self.pool = quantref.make_images(seed, int(cfg["image_pool"]), hw,
+                                         c, stream=2)
+        graph = model.graph(self.params, cfg)
         policy = QuantPolicy(
             mode="serial", w_bits=int(cfg["w_bits"]),
             a_bits=int(cfg["a_bits"]), w_signed=bool(cfg["w_signed"]),
@@ -279,8 +281,9 @@ def traced_context(served, plan, rec, done, win, profile_dir):
     in_trace = int(np.sum((t_done * 1e9 >= win.t_start)
                           & (t_done * 1e9 <= win.t_stop)))
     return types.SimpleNamespace(
-        cfg=plan["config"], traffic=plan["traffic"], trace=red,
-        offset_ns=offset, spans=served.spans, trace_lo=lo, trace_hi=hi,
+        cfg=plan["config"], model=plan["model"], traffic=plan["traffic"],
+        trace=red, offset_ns=offset, spans=served.spans, trace_lo=lo,
+        trace_hi=hi,
         window=(rec.t0 * 1e9, win.t_stop),
         images_in_trace=in_trace, peaks=served.peaks)
 
@@ -367,8 +370,8 @@ def run(plan: dict, seed: int, seconds: float, traced: bool, *,
         served.close()
         del served
         got = np.stack([np.asarray(g) for g in got])
-        ref = reference.logits(params, calib, pool[served_imgs],
-                               plan["config"])
+        ref = plan["model"].logits(params, calib, pool[served_imgs],
+                                   plan["config"])
         nums = numbers(got, ref)
     finally:
         if profile_dir is not None:

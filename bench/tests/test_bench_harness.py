@@ -38,6 +38,8 @@ def test_every_cell_resolves_with_readers():
     for wl in bm["workloads"]:
         plan = spec.cell(ROOT, wl["name"])
         assert plan["config"]["layers"]
+        assert all(callable(getattr(plan["model"], f))
+                   for f in spec.MODEL_FUNCTIONS)
         assert any(m["name"] == "setup_s" for m in plan["end_to_end"])
         assert len(plan["end_to_end"]) >= 2 and plan["per_layer"]
         for m in plan["per_layer"]:
@@ -47,10 +49,7 @@ def test_every_cell_resolves_with_readers():
 def test_new_cell_and_metric_are_files_only(tmp_path):
     """A cell, a mix, a configuration and a per-layer metric added as
     files and entries are found without a code change."""
-    shutil.copy(ROOT / "BENCHMARK.json", tmp_path / "BENCHMARK.json")
-    shutil.copytree(ROOT / "bench", tmp_path / "bench",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    bench = tmp_path / "bench"
+    bench = _copy_bench(tmp_path)
     cfg = json.loads((bench / "configs" / "resnet9-w4a8.json").read_text())
     cfg["max_batch"] = 64
     (bench / "configs" / "resnet9-w4a8-b64.json").write_text(json.dumps(cfg))
@@ -104,3 +103,41 @@ def test_compile_count_sees_programs_built_in_the_window():
     with CompileCount() as cold:
         jnp.ones((3, 6)).sum()
     assert warm.n == 0 and cold.n >= 1
+
+
+def _copy_bench(tmp_path):
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path / "BENCHMARK.json")
+    shutil.copytree(ROOT / "bench", tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    return tmp_path / "bench"
+
+
+@pytest.mark.parametrize("model, module", [
+    ("no-such-model", None),
+    ("half", "def make_params(cfg, seed):\n    pass\n"),
+    ("../models/resnet9", None),
+])
+def test_unknown_model_is_refused_before_jax(tmp_path, model, module):
+    """A configuration whose model module is missing or lacks a required
+    function is a SpecError, and the cell command exits 2 without
+    importing JAX."""
+    bench = _copy_bench(tmp_path)
+    if module is not None:
+        (bench / "models" / f"{model}.py").write_text(module)
+    cfg_path = bench / "configs" / "resnet9-w2a2.json"
+    cfg_path.write_text(json.dumps(dict(json.loads(cfg_path.read_text()),
+                                        model=model)))
+    with pytest.raises(spec.SpecError):
+        spec.cell(tmp_path, "resnet9-w2a2.saturate")
+    code = ("import runpy, sys\n"
+            f"sys.argv = ['run_cell.py', '--workload', "
+            "'resnet9-w2a2.saturate', '--seed', '1', '--seconds', '1']\n"
+            "try:\n"
+            f"    runpy.run_path({str(bench / 'run_cell.py')!r}, "
+            "run_name='__main__')\n"
+            "except SystemExit as e:\n"
+            "    print(e.code, 'jax' in sys.modules)\n")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120)
+    assert r.stdout.split() == ["2", "False"], (r.stdout, r.stderr)
+    assert "model" in r.stderr

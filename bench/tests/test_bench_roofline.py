@@ -1,13 +1,16 @@
-"""Operation and byte counts of the served ResNet9, and the peaks table."""
+"""Operation and byte counts of the served ResNet9 (its model module's
+convs and multiply-accumulates, counted by ``bench/roofline.py``), and the
+peaks table."""
 
 import json
 from pathlib import Path
 
 import pytest
 
-from bench import roofline
+from bench import roofline, spec
 
 ROOT = Path(__file__).resolve().parents[2]
+RESNET9 = spec.model(ROOT, "resnet9")
 
 
 def _cfg(name="resnet9-w2a2"):
@@ -24,10 +27,10 @@ def test_macs_match_an_independent_count():
     assert hidden == 245_366_784
     cfg = _cfg()
     per_layer = [roofline.conv_macs(l, 1)
-                 for l in roofline.hidden_layers(cfg)]
+                 for l in RESNET9.convs(cfg)]
     assert sum(per_layer) == hidden
-    assert roofline.macs_per_image(cfg) == (hidden + 32 * 32 * 27 * 64
-                                            + 512 * 10)
+    assert RESNET9.macs_per_image(cfg) == (hidden + 32 * 32 * 27 * 64
+                                           + 512 * 10)
 
 
 @pytest.mark.parametrize("name", ["resnet9-w2a2", "resnet9-w4a8"])
@@ -35,14 +38,15 @@ def test_layers_match_the_programs_geometry(name):
     """Every hidden conv reads the map, widths and stride of the program's
     transcription of the paper's Table 3 (``RESNET9_CIFAR10``)."""
     from repro.core.cost_model import RESNET9_CIFAR10, ConvLayer
-    ours = {l["name"]: l for l in roofline.hidden_layers(_cfg(name))}
+    ours = {l["name"]: l for l in RESNET9.convs(_cfg(name))}
     paper = [l for l in RESNET9_CIFAR10
              if isinstance(l, ConvLayer) and not l.on_host]
     assert [l.name for l in paper] == list(ours)
     for l in paper:
-        assert (l.h, l.c_in, l.c_out, l.stride) == (
-            ours[l.name]["h"], ours[l.name]["ci"], ours[l.name]["co"],
-            ours[l.name]["stride"])
+        o = ours[l.name]
+        assert (l.h, l.w, l.c_in, l.c_out, l.stride) == (
+            o["h"], o["w"], o["ci"], o["co"], o["stride"])
+        assert (l.fh, l.fw, l.padding) == (o["fh"], o["fw"], o["padding"])
         assert ours[l.name]["ho"] == (l.h + 2 * l.padding - l.fh) \
             // l.stride + 1
 
@@ -51,7 +55,7 @@ def test_layers_match_the_programs_geometry(name):
 def test_roofline_time_does_not_fall_as_bits_rise(n):
     pk = roofline.peaks(ROOT, "TPU v5 lite")
     cfg = _cfg()
-    for layer in roofline.hidden_layers(cfg):
+    for layer in RESNET9.convs(cfg):
         prev = 0.0
         for w, a in [(1, 1), (2, 2), (2, 4), (4, 4), (4, 8), (8, 8)]:
             c = dict(cfg, w_bits=w, a_bits=a)

@@ -5,9 +5,10 @@ from pathlib import Path
 
 import pytest
 
-from bench import trace
+from bench import spec, trace
 
 DATA = Path(__file__).parent / "data"
+RESNET9 = spec.model(Path(__file__).resolve().parents[2], "resnet9")
 
 
 def test_busy_counts_overlapping_operations_once():
@@ -43,7 +44,7 @@ def test_conv_calls_are_matched_to_layers_by_operand_shapes():
     other = "%fusion.3 = f32[256,32,32,64]{3,2,1,0} fusion(%a), kind=kLoop"
     ops = [("/device:TPU:0", conv3, 0.0, 2e6),
            ("/device:TPU:0", other, 2e6, 3e6)]
-    calls = kernels.conv_calls(ops, cfg)
+    calls = kernels.conv_calls(ops, RESNET9.convs(cfg), cfg)
     assert len(calls) == 1
     layer, n, secs = calls[0]
     assert layer["name"] == "conv3" and n == 256
@@ -66,10 +67,32 @@ def test_recorded_chip_trace():
     hi = max(b for _, _, b in ops["/device:TPU:0"])
     red = trace.reduce(prof, lo, hi)
     assert 0 < red["busy_s"] < red["window_s"]
-    calls = kernels.conv_calls(red["ops"], cfg)
+    calls = kernels.conv_calls(red["ops"], RESNET9.convs(cfg), cfg)
     # eight hidden convs per step (conv1 and conv2 share a shape)
     assert sorted(n for _, n, _ in calls) == [8] * 8 + [256] * 8
     names = [l["name"] for l, n, _ in calls if n == 256]
     assert sorted(set(names)) == ["conv2", "conv3", "conv4", "conv5",
                                   "conv6", "conv7", "conv8"]
     assert all(t > 0 for _, _, t in calls)
+
+
+def test_recorded_chip_trace_readings_are_pinned():
+    """The conv roofline and whole-step readers, through the ResNet9 model
+    module, read the recorded trace as the harness that read ResNet9's
+    shapes directly did (values pinned from it, 264 images answered)."""
+    import json
+    import types
+    from bench import roofline
+    root = Path(__file__).resolve().parents[2]
+    cfg = json.loads((root / "bench" / "configs" / "resnet9-w2a2.json")
+                     .read_text())
+    prof = trace.load(DATA / "v5e_w2a2_saturate.xplane.pb")
+    hi = max(b for _, _, b in trace.device_ops(prof)["/device:TPU:0"])
+    ctx = types.SimpleNamespace(
+        trace=trace.reduce(prof, trace.host_marker(prof), hi), cfg=cfg,
+        model=RESNET9, peaks=roofline.peaks(root, "TPU v5 lite"),
+        images_in_trace=264)
+    assert spec.reader(root, "conv_roofline.saturate")(ctx) \
+        == 0.965518282809736
+    assert spec.reader(root, "step_mfu.saturate")(ctx) \
+        == 0.41079539250537694
